@@ -49,7 +49,14 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref, *,
     u = u_ref[0].astype(jnp.float32)          # (1, d) bonus
     S = state_ref[...]                        # (d, d)
 
-    cum = jnp.cumsum(lw, axis=0)              # inclusive (C, d)
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over time as a lower-triangular matmul (Mosaic
+    # has no cumsum lowering)
+    cum = jax.lax.dot_general(jnp.where(t_idx >= s_idx, 1.0, 0.0), lw,
+                              (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)  # (C, d)
     cum_excl = cum - lw
     # inter-chunk: decayed read of the carried state (factor <= 1, exact)
     r_decay = r * jnp.exp(cum_excl)           # (C, d)
@@ -59,23 +66,26 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref, *,
     # intra-chunk pairwise scores.  score[t,s] = sum_i r*k*e^{cum_excl[t,i]
     # - cum[s,i]} is separable; a per-channel midpoint offset c_i keeps both
     # factors within f32 range (exact: offsets cancel in the product).
-    c_off = 0.5 * cum[-1]                     # (d,)
-    r_sc = r * jnp.exp(cum_excl - c_off[None, :])
-    k_sc = k * jnp.exp(c_off[None, :] - cum)
+    last = cum[chunk - 1:chunk]               # (1, d) chunk log-decay
+    c_off = 0.5 * last
+    r_sc = r * jnp.exp(cum_excl - c_off)
+    k_sc = k * jnp.exp(c_off - cum)
     scores = jax.lax.dot_general(r_sc, k_sc, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (C, C)
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    s_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(t_idx > s_idx, scores, 0.0)
-    diag = jnp.sum(r * u * k, axis=1)         # (C,)
+    diag = jnp.sum(r * u * k, axis=1, keepdims=True)   # (C, 1)
     o = o + jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-    o = o + diag[:, None] * v
+    o = o + diag * v
 
-    # state propagation to the next chunk
-    decay_all = jnp.exp(cum[-1])              # (d,)
-    k_carry = k * jnp.exp(cum[-1][None, :] - cum)      # (C, d)
-    state_ref[...] = (S * decay_all[:, None]
+    # state propagation to the next chunk; S's rows scale by the chunk's
+    # decay, summed as a column (d, 1) so no vector transpose is needed
+    last_col = jax.lax.dot_general(lw, jnp.ones((chunk, 1), jnp.float32),
+                                   (((0,), (0,)), ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+    k_carry = k * jnp.exp(last - cum)         # (C, d)
+    state_ref[...] = (S * jnp.exp(last_col)
                       + jax.lax.dot_general(k_carry, v, (((0,), (0,)), ((), ())),
                                             preferred_element_type=jnp.float32))
     o_ref[0] = o.astype(o_ref.dtype)

@@ -1,8 +1,12 @@
-"""Batched serving driver: prefill + decode loop with a sharded KV cache.
+"""Batched serving driver: prefill + decode loop over a KV cache.
 
-``python -m repro.launch.serve --arch qwen2.5-3b --reduced --tokens 32``
-greedy-decodes a batch of synthetic prompts.  On a pod the same driver uses
-the TileLoom decode plan (kv-sequence-split when kv_heads < TP, DESIGN.md).
+``python -m repro.launch.serve --arch qwen2.5-3b`` greedy-decodes a batch of
+synthetic prompts (batch 4, prompt 128, 32 new tokens by default) at the
+architecture's published width, with random weights from ``--seed`` kept in
+the compute dtype; ``--reduced`` serves the small same-family config.  The
+mesh planner's decode ranking is printed; the sharded serve step under that
+plan is ``train/serve_step.jit_serve_step``.  Set-up (planning, weight init,
+compilation) is reported apart from prefill and decode time.
 
 Serving-layer observability (DESIGN_OBS.md): ``--introspect-port`` starts
 a read-only HTTP endpoint (``/metrics`` Prometheus text, ``/healthz``,
@@ -13,16 +17,21 @@ ring buffer at exit for ``python -m repro.obs incident PATH``.
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import functools
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import plancache
 from repro.configs import get_config
 from repro.obs import expo, flightrec, metrics, slo
-from repro.configs.base import ShapeConfig, TrainConfig
+from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.data import DataConfig, make_source
+from repro.launch import use_compile_cache
 from repro.models import build_model
 from repro.planservice import PlanService
 
@@ -175,13 +184,143 @@ def _run_tenants(args, obs) -> None:
         print(f"[serve] metrics snapshot written to {dumped}")
 
 
+def serving_config(arch: str, *, reduced: bool = False) -> ModelConfig:
+    """The architecture's config as the server holds it: weights stored in
+    the compute dtype.  Float32 weights do not fit one 16 GB chip at
+    qwen2.5-3b's width: XLA hoists the per-step float32->bfloat16 casts of
+    the scanned block weights out of the layer loop as whole-stack
+    converts, on top of the float32 stacks themselves."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
+    return dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
+
+
+def init_params(api, seed: int, shardings=None):
+    """Random weights from ``seed``, made on the device (placed by
+    ``shardings`` when given) and waited for."""
+    init = jax.jit(api.init, out_shardings=shardings)
+    return jax.block_until_ready(init(jax.random.PRNGKey(seed)))
+
+
+def synthetic_prompts(cfg: ModelConfig, batch: int, prompt_len: int,
+                      seed: int) -> np.ndarray:
+    """Host-side prompt ids (batch, prompt_len), as requests arrive."""
+    source = make_source(DataConfig(seed=seed, vocab_size=cfg.vocab_size),
+                         cfg)
+    return np.asarray(source.batch_at(0, batch, prompt_len)["tokens"],
+                      np.int32)
+
+
+@dataclasses.dataclass
+class GreedyRun:
+    """One greedy generation: generated ids (B, n), the logits each id was
+    picked from (B, n, vocab; row 0 is the last prompt position), and the
+    host-clock seconds of each phase (each ends in ``block_until_ready``)."""
+    generated: jax.Array
+    logits: jax.Array
+    prefill_s: float
+    decode_s: float
+
+
+def _pick(logits: jax.Array, vocab_size: int):
+    """Greedy choice from a decode step's logits: the last position's row
+    over the real vocabulary, and its argmax as the next (B, 1) tokens."""
+    row = logits[:, -1, :vocab_size]
+    return row, jnp.argmax(row, axis=-1)[:, None].astype(jnp.int32)
+
+
+def compile_greedy(step, params, tokens, cache, vocab_size: int):
+    """AOT-compile ``step`` (a jitted ``decode_step``) and the greedy pick
+    for these arguments, so that the timed loop compiles nothing.
+    Returns ``(decode, pick)``."""
+    decode = step.lower(params, tokens, cache).compile()
+    pick = jax.jit(functools.partial(_pick, vocab_size=vocab_size)).lower(
+        decode.out_info[0]).compile()
+    return decode, pick
+
+
+def greedy_generate(decode, pick, params, prompts, cache,
+                    n_tokens: int) -> GreedyRun:
+    """Feed the prompt (host ids, B x P) token by token through ``decode``,
+    then greedy-decode ``n_tokens`` ids; ``decode``/``pick`` come from
+    :func:`compile_greedy`."""
+    prompts = np.asarray(prompts)
+    t0 = time.perf_counter()
+    for t in range(prompts.shape[1]):
+        logits, cache = decode(params, prompts[:, t:t + 1], cache)
+    row, tok = pick(logits)
+    jax.block_until_ready(tok)
+    prefill_s = time.perf_counter() - t0
+
+    rows, out = [row], [tok]
+    t0 = time.perf_counter()
+    for _ in range(n_tokens - 1):
+        logits, cache = decode(params, tok, cache)
+        row, tok = pick(logits)
+        rows.append(row)
+        out.append(tok)
+    jax.block_until_ready(tok)
+    decode_s = time.perf_counter() - t0
+    return GreedyRun(jnp.concatenate(out, axis=1), jnp.stack(rows, axis=1),
+                     prefill_s, decode_s)
+
+
+def serve_model(arch: str, *, reduced: bool = False, batch: int = 4,
+                prompt_len: int = 128, tokens: int = 32, seed: int = 0,
+                plan_budget_ms: Optional[float] = None):
+    """Serve one batch of synthetic prompts on the default device.
+
+    Returns ``(api, params, prompts, run)``.  Set-up (mesh planning, weight
+    init, compilation) is timed apart from prefill and decode."""
+    cfg = serving_config(arch, reduced=reduced)
+    api = build_model(cfg)
+    t_setup = time.perf_counter()
+    shape = ShapeConfig("serve", seq_len=prompt_len + tokens,
+                        global_batch=batch, kind="decode")
+    # the serving loop never stalls on planning: the deadline-bounded
+    # service answers from cache / family / bounded search / fallback
+    resp = PlanService().resolve_mesh(api, shape, TrainConfig(),
+                                      budget_ms=plan_budget_ms)
+    ranking = resp.ranking or []
+    print(f"[serve] {cfg.name}: decode plan ranking "
+          f"(rung={resp.rung} {resp.seconds * 1e3:.1f}ms): "
+          + ", ".join(r.plan.name for r in ranking[:3]))
+    plancache.get_store().flush_stats()
+    plan_s = time.perf_counter() - t_setup
+
+    t0 = time.perf_counter()
+    params = init_params(api, seed)
+    prompts = synthetic_prompts(cfg, batch, prompt_len, seed)
+    cache = api.init_cache(cfg, batch, prompt_len + tokens + 1)
+    init_s = time.perf_counter() - t0
+
+    t0 = time.perf_counter()
+    decode, pick = compile_greedy(
+        jax.jit(api.decode_step, donate_argnums=(2,)), params,
+        prompts[:, :1], cache, cfg.vocab_size)
+    compile_s = time.perf_counter() - t0
+    setup_s = time.perf_counter() - t_setup
+    print(f"[serve] set-up {setup_s:.2f}s: plan {plan_s:.2f}s, "
+          f"init {init_s:.2f}s, compile {compile_s:.2f}s "
+          f"({api.n_params():,} params, {cfg.param_dtype})")
+
+    run = greedy_generate(decode, pick, params, prompts, cache, tokens)
+    print(f"[serve] prefill {prompt_len} tok x{batch}: {run.prefill_s:.3f}s; "
+          f"decode {tokens} tok x{batch}: {run.decode_s:.3f}s "
+          f"({tokens * batch / run.decode_s:.1f} tok/s)")
+    return api, params, prompts, run
+
+
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
+    ap.add_argument("--prompt-len", type=int, default=128)
     ap.add_argument("--tokens", type=int, default=32)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and synthetic prompts")
     ap.add_argument("--plan-budget-ms", type=float, default=None,
                     help="plan-service deadline (default "
                          "$REPRO_PLAN_DEADLINE_MS / 10ms)")
@@ -211,60 +350,19 @@ def main(argv=None) -> None:
                          "render with `python -m repro.obs incident PATH`")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     obs = _setup_observability(args)
     if args.tenants > 0:
         _run_tenants(args, obs)
         _finish_observability(args, obs)
         return
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    api = build_model(cfg)
-    shape = ShapeConfig("serve", seq_len=args.prompt_len + args.tokens,
-                        global_batch=args.batch, kind="decode")
-    # the serving loop never stalls on planning: the deadline-bounded
-    # service answers from cache / family / bounded search / fallback
-    service = PlanService()
-    resp = service.resolve_mesh(api, shape, TrainConfig(),
-                                budget_ms=args.plan_budget_ms)
-    ranking = resp.ranking or []
-    print(f"[serve] {cfg.name}: decode plan ranking "
-          f"(rung={resp.rung} {resp.seconds * 1e3:.1f}ms): "
-          + ", ".join(r.plan.name for r in ranking[:3]))
-    plancache.get_store().flush_stats()
-
-    params = api.init(jax.random.PRNGKey(0))
-    source = make_source(DataConfig(vocab_size=cfg.vocab_size), cfg)
-    prompts = jnp.asarray(source.batch_at(0, args.batch,
-                                          args.prompt_len)["tokens"])
-    max_len = args.prompt_len + args.tokens + 1
-    cache = api.init_cache(cfg, args.batch, max_len)
-    decode = jax.jit(api.decode_step)
-
-    # prefill token-by-token (reduced models; a pod launcher uses the fused
-    # prefill path of launch/dryrun.py's prefill_step)
-    t0 = time.perf_counter()
-    logits = None
-    for t in range(args.prompt_len):
-        logits, cache = decode(params, prompts[:, t:t + 1], cache)
-    prefill_s = time.perf_counter() - t0
-
-    out = []
-    tok = jnp.argmax(logits[:, -1:, :cfg.vocab_size], axis=-1).astype(jnp.int32)
-    t0 = time.perf_counter()
-    for _ in range(args.tokens):
-        out.append(tok)
-        logits, cache = decode(params, tok, cache)
-        tok = jnp.argmax(logits[:, -1:, :cfg.vocab_size],
-                         axis=-1).astype(jnp.int32)
-    jax.block_until_ready(tok)
-    decode_s = time.perf_counter() - t0
-    gen = jnp.concatenate(out, axis=1)
-    print(f"[serve] prefill {args.prompt_len} tok x{args.batch}: "
-          f"{prefill_s:.2f}s; decode {args.tokens} tok x{args.batch}: "
-          f"{decode_s:.2f}s ({args.tokens * args.batch / decode_s:.1f} tok/s)")
-    print(f"[serve] sample generation (ids): {gen[0, :16].tolist()}")
+    *_, run = serve_model(args.arch, reduced=args.reduced,
+                          batch=args.batch, prompt_len=args.prompt_len,
+                          tokens=args.tokens, seed=args.seed,
+                          plan_budget_ms=args.plan_budget_ms)
+    print(f"[serve] sample generation (ids): "
+          f"{run.generated[0, :16].tolist()}")
     counts = metrics.counter_totals(metrics.snapshot())
     if counts:
         print("[serve] metrics: " + " ".join(

@@ -28,7 +28,7 @@ from repro.runtime import (HeartbeatRegistry, ResilientDriver,
                            StragglerTracker)
 from repro.runtime.faults import env_schedule
 from repro.train import train_step as TS
-from .mesh import make_host_mesh
+from . import use_compile_cache
 
 
 def main(argv=None) -> None:
@@ -48,6 +48,7 @@ def main(argv=None) -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

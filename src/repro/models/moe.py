@@ -147,10 +147,7 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig
       crosses a shard boundary (GSPMD cannot shard those; see DESIGN.md S8).
     * **single-shard** fallback (tests, CPU smoke): same dispatch over all E.
     """
-    try:
-        from jax import shard_map
-    except ImportError:              # jax < 0.6: experimental namespace
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.parallel import sharding as SH
 

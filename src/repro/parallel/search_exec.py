@@ -87,6 +87,21 @@ def _mp_context():
     return multiprocessing.get_context(name)
 
 
+def _pin_worker_to_cpu() -> None:
+    """Pool initializer: keep every planner worker off the accelerator.
+
+    An accelerator belongs to one process at a time, and a parent that has
+    touched JAX already holds it, so a worker that initialised a TPU
+    backend would fail or hang.  Planning is host work; the worker's JAX
+    (if anything imports it) gets the CPU backend only.  The environment
+    covers a later ``import jax``; the config update covers a JAX that the
+    spawn bootstrap already imported (through the parent's ``__main__``)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import sys
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+
+
 def resolve_workers(workers: Optional[int] = None) -> int:
     """The effective worker count: the caller's explicit value, else
     ``REPRO_PLANNER_WORKERS``, else ``os.cpu_count()``.  Values <= 1 (and
@@ -115,7 +130,8 @@ def _get_pool(workers: int) -> ProcessPoolExecutor:
         return _POOL
     if _POOL is not None:
         _POOL.shutdown(wait=False, cancel_futures=True)
-    _POOL = ProcessPoolExecutor(max_workers=workers, mp_context=_mp_context())
+    _POOL = ProcessPoolExecutor(max_workers=workers, mp_context=_mp_context(),
+                                initializer=_pin_worker_to_cpu)
     _POOL_WORKERS = workers
     return _POOL
 

@@ -1,5 +1,9 @@
 """Integration tests: end-to-end training with checkpoint/restart, sharded
 execution on a small host mesh, decode consistency vs teacher forcing."""
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -128,3 +132,55 @@ def test_rwkv_decode_matches_teacher_forcing():
     np.testing.assert_allclose(np.asarray(dec, np.float32),
                                np.asarray(full, np.float32),
                                rtol=0.05, atol=0.05)
+
+
+def test_serve_model_keeps_weights_in_compute_dtype():
+    """The serving driver stores weights in the compute dtype and each
+    greedy id is the argmax of the logits row it was picked from."""
+    from repro.launch import serve
+    api, params, prompts, run = serve.serve_model(
+        "qwen2.5-3b", reduced=True, batch=2, prompt_len=4, tokens=3)
+    dtypes = {leaf.dtype for leaf in jax.tree.leaves(params)}
+    assert dtypes == {jnp.dtype(api.cfg.compute_dtype)}
+    assert prompts.shape == (2, 4)
+    assert run.generated.shape == (2, 3)
+    assert run.logits.shape == (2, 3, api.cfg.vocab_size)
+    np.testing.assert_array_equal(np.asarray(run.generated),
+                                  np.asarray(run.logits.argmax(-1)))
+
+
+_CACHE_PROBE = """
+import sys
+sys.path.insert(0, sys.argv[1])
+from repro.launch import use_compile_cache
+print(use_compile_cache())
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.block_until_ready(jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)))
+"""
+
+
+def test_compile_cache_location(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is where compiles are cached;
+    unset, the entry points use <repo>/.jax_cache, which git ignores."""
+    from repro.launch import REPO_ROOT, use_compile_cache
+    env_dir = tmp_path / "jax_cache"
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE, str(REPO_ROOT / "src")],
+        env={**os.environ,
+             "JAX_COMPILATION_CACHE_DIR": str(env_dir),
+             "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=120, check=True)
+    assert out.stdout.split("\n")[0] == str(env_dir)
+    assert any(env_dir.iterdir())
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        assert use_compile_cache() == str(REPO_ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            REPO_ROOT / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    ignored = (REPO_ROOT / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored

@@ -15,6 +15,7 @@ The cold-path optimizations must not change what the planner selects:
   for any worker count.
 """
 import math
+import os
 
 import pytest
 
@@ -318,6 +319,27 @@ def test_parallel_matches_inline(workers):
                       c.sim.total_s if c.sim else None) for c in r.topk]
     assert key(sharded) == key(inline)
     assert sharded.best.plan == inline.best.plan
+
+
+def _worker_backend(_):
+    import jax
+    return (os.environ.get("JAX_PLATFORMS"), jax.config.jax_platforms,
+            jax.devices()[0].platform)
+
+
+def test_pool_workers_never_reach_the_accelerator(monkeypatch):
+    """A planning process that has imported JAX (and so may hold the chip)
+    starts pool workers that can only reach JAX's CPU backend, even when
+    the parent's environment asks for the TPU."""
+    import jax  # noqa: F401 - the parent process holds JAX
+    from repro.parallel import search_exec
+    search_exec.shutdown_pool()
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    try:
+        got = search_exec.map_jobs(_worker_backend, [0, 1], workers=2)
+    finally:
+        search_exec.shutdown_pool()
+    assert got == [("cpu", "cpu", "cpu")] * 2
 
 
 def test_parallel_env_knob_and_infeasible_accounting(monkeypatch):
