@@ -28,7 +28,7 @@ import numpy as np
 
 from repro import plancache
 from repro.configs import get_config
-from repro.obs import expo, flightrec, metrics, slo
+from repro.obs import expo, flightrec, metrics, slo, trace
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.data import DataConfig, make_source
 from repro.launch import use_compile_cache
@@ -233,37 +233,65 @@ def _pick(logits: jax.Array, vocab_size: int):
 def compile_greedy(step, params, tokens, cache, vocab_size: int):
     """AOT-compile ``step`` (a jitted ``decode_step``) and the greedy pick
     for these arguments, so that the timed loop compiles nothing.
-    Returns ``(decode, pick)``."""
+    Returns ``(decode, pick)``.  Also re-reads ``REPRO_TRACE`` and starts
+    counting JAX's compilations (``jax_compiles_total``)."""
+    trace.refresh_from_env()
+    metrics.watch_compiles()
     decode = step.lower(params, tokens, cache).compile()
     pick = jax.jit(functools.partial(_pick, vocab_size=vocab_size)).lower(
         decode.out_info[0]).compile()
     return decode, pick
 
 
+def _loop_spans():
+    """What opens the serving loop's spans for one call: ``repro.obs``
+    spans (which also reach a profiler capture) while tracing is on; else,
+    while a JAX profiler capture runs, bare ``TraceAnnotation``s, so that
+    the capture names the loop's idle gaps; else the shared no-op."""
+    if not trace.enabled() and jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation
+    return functools.partial(trace.span, cat="serve")
+
+
 def greedy_generate(decode, pick, params, prompts, cache,
                     n_tokens: int) -> GreedyRun:
     """Feed the prompt (host ids, B x P) token by token through ``decode``,
     then greedy-decode ``n_tokens`` ids; ``decode``/``pick`` come from
-    :func:`compile_greedy`."""
+    :func:`compile_greedy`.
+
+    Spans (category ``serve``, DESIGN_OBS.md): ``serve.prefill`` and
+    ``serve.decode`` cover the two phases, ``serve.step`` each feed and
+    dispatch, ``serve.sync`` each wait on the device, ``serve.collect``
+    the closing concatenate and stack.  They are recorded while tracing
+    is on, and reach a running JAX profiler capture either way."""
     prompts = np.asarray(prompts)
+    span = _loop_spans()
     t0 = time.perf_counter()
-    for t in range(prompts.shape[1]):
-        logits, cache = decode(params, prompts[:, t:t + 1], cache)
-    row, tok = pick(logits)
-    jax.block_until_ready(tok)
+    with span("serve.prefill"):
+        for t in range(prompts.shape[1]):
+            with span("serve.step"):
+                logits, cache = decode(params, prompts[:, t:t + 1], cache)
+        row, tok = pick(logits)
+        with span("serve.sync"):
+            jax.block_until_ready(tok)
     prefill_s = time.perf_counter() - t0
 
     rows, out = [row], [tok]
     t0 = time.perf_counter()
-    for _ in range(n_tokens - 1):
-        logits, cache = decode(params, tok, cache)
-        row, tok = pick(logits)
-        rows.append(row)
-        out.append(tok)
-    jax.block_until_ready(tok)
+    with span("serve.decode"):
+        for _ in range(n_tokens - 1):
+            with span("serve.step"):
+                logits, cache = decode(params, tok, cache)
+                row, tok = pick(logits)
+                rows.append(row)
+                out.append(tok)
+        with span("serve.sync"):
+            jax.block_until_ready(tok)
     decode_s = time.perf_counter() - t0
-    return GreedyRun(jnp.concatenate(out, axis=1), jnp.stack(rows, axis=1),
-                     prefill_s, decode_s)
+    with span("serve.collect"):
+        generated = jnp.concatenate(out, axis=1)
+        logits = jnp.stack(rows, axis=1)
+    return GreedyRun(generated, logits, prefill_s, decode_s)
 
 
 def serve_model(arch: str, *, reduced: bool = False, batch: int = 4,

@@ -220,6 +220,9 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
     * cross-attention: ``kv_input`` projects k/v from another sequence, or
       ``precomputed_kv`` supplies already-projected (k, v) (cached cross
       attention during decode).
+
+    Named scopes: ``proj`` (q/k/v/o projections and RoPE), ``kv_cache``
+    (the cache write) and ``core`` (K/V repeat, scores, softmax, p.v).
     """
     B, S, d = x.shape
     hd = cfg.head_dim_
@@ -233,45 +236,52 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
         out = _sdpa_xla(q, kr, vr, False, hd ** -0.5)
         out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
         return constrain(out, ("batch", "seq", "embed")), None
-    q, k, v = _project_qkv(p, x, cfg, kv_input)
-    if use_rope and kv_input is None:
-        pos = positions if positions is not None else jnp.arange(S)
-        cos, sin = rope_frequencies(hd, cfg.rope_theta, pos)
-        if kv_cache is not None and cache_index is not None:
-            qpos = cache_index + jnp.arange(S)
-            qcos, qsin = rope_frequencies(hd, cfg.rope_theta, qpos)
-            q = apply_rope(q, qcos, qsin)
-            k = apply_rope(k, qcos, qsin)
-        else:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+    with jax.named_scope("proj"):
+        q, k, v = _project_qkv(p, x, cfg, kv_input)
+        if use_rope and kv_input is None:
+            pos = positions if positions is not None else jnp.arange(S)
+            cos, sin = rope_frequencies(hd, cfg.rope_theta, pos)
+            if kv_cache is not None and cache_index is not None:
+                qpos = cache_index + jnp.arange(S)
+                qcos, qsin = rope_frequencies(hd, cfg.rope_theta, qpos)
+                q = apply_rope(q, qcos, qsin)
+                k = apply_rope(k, qcos, qsin)
+            else:
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
     new_cache = None
     if kv_cache is not None:
-        ck, cv = kv_cache
-        if cache_index is not None:
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype),
-                                                     cache_index, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype),
-                                                     cache_index, axis=1)
-        k, v = ck, cv
-        new_cache = (ck, cv)
-        k = constrain(k, ("batch", "kv_seq", "kv_heads", "head_dim"))
-        v = constrain(v, ("batch", "kv_seq", "kv_heads", "head_dim"))
-    kr = _repeat_kv(k, cfg.q_per_kv)
-    vr = _repeat_kv(v, cfg.q_per_kv)
-    sm_scale = hd ** -0.5
-    is_causal = causal and kv_input is None and kv_cache is None
-    if cfg.kernels == "pallas" and (kv_cache is None or cache_index is None):
-        # pallas decode path assumes a fully-valid cache (production kernels
-        # take a length scalar; the xla path below masks exactly)
-        out = _sdpa_pallas(q, kr, vr, is_causal, sm_scale)
-    else:
-        valid = (cache_index + S) if (kv_cache is not None
-                                      and cache_index is not None) else None
-        out = _sdpa_xla(q, kr, vr, is_causal, sm_scale, kv_valid_len=valid)
-    out = constrain(out, ("batch", "seq", "q_heads", "head_dim"))
-    out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
-    out = constrain(out, ("batch", "seq", "embed"))
+        with jax.named_scope("kv_cache"):
+            ck, cv = kv_cache
+            if cache_index is not None:
+                ck = jax.lax.dynamic_update_slice_in_dim(
+                    ck, k.astype(ck.dtype), cache_index, axis=1)
+                cv = jax.lax.dynamic_update_slice_in_dim(
+                    cv, v.astype(cv.dtype), cache_index, axis=1)
+            k, v = ck, cv
+            new_cache = (ck, cv)
+            k = constrain(k, ("batch", "kv_seq", "kv_heads", "head_dim"))
+            v = constrain(v, ("batch", "kv_seq", "kv_heads", "head_dim"))
+    with jax.named_scope("core"):
+        kr = _repeat_kv(k, cfg.q_per_kv)
+        vr = _repeat_kv(v, cfg.q_per_kv)
+        sm_scale = hd ** -0.5
+        is_causal = causal and kv_input is None and kv_cache is None
+        if cfg.kernels == "pallas" and (kv_cache is None
+                                        or cache_index is None):
+            # pallas decode path assumes a fully-valid cache (production
+            # kernels take a length scalar; the xla path below masks
+            # exactly)
+            out = _sdpa_pallas(q, kr, vr, is_causal, sm_scale)
+        else:
+            cached = kv_cache is not None and cache_index is not None
+            valid = cache_index + S if cached else None
+            out = _sdpa_xla(q, kr, vr, is_causal, sm_scale,
+                            kv_valid_len=valid)
+        out = constrain(out, ("batch", "seq", "q_heads", "head_dim"))
+    with jax.named_scope("proj"):
+        out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
+        out = constrain(out, ("batch", "seq", "embed"))
     return out, new_cache
 
 

@@ -63,43 +63,46 @@ def _dispatch_ffn_combine(xf: jax.Array, p_gate, p_up, p_down,
     """
     T, d = xf.shape
     k = expert_idx.shape[-1]
-    e_flat = expert_idx.reshape(T * k)
-    w_flat = gate_vals.reshape(T * k)
-    tok_flat = jnp.arange(T * k, dtype=jnp.int32) // k
-    local = e_flat - e_lo                                     # local slot id
-    in_range = (local >= 0) & (local < n_local)
-    local_c = jnp.where(in_range, local, n_local)             # park OOR at end
-    order = jnp.argsort(local_c)                              # stable
-    se = local_c[order]
-    st = tok_flat[order]
-    sw = w_flat[order]
-    counts = jnp.bincount(local_c, length=n_local + 1)[:n_local]
-    starts = jnp.cumsum(counts) - counts                      # (n_local,)
-    se_c = jnp.minimum(se, n_local - 1)
-    rank = jnp.arange(T * k, dtype=jnp.int32) - starts[se_c]
-    keep = (se < n_local) & (rank >= 0) & (rank < cap)
-    rank_c = jnp.where(keep, rank, 0)
+    with jax.named_scope("dispatch"):
+        e_flat = expert_idx.reshape(T * k)
+        w_flat = gate_vals.reshape(T * k)
+        tok_flat = jnp.arange(T * k, dtype=jnp.int32) // k
+        local = e_flat - e_lo                                 # local slot id
+        in_range = (local >= 0) & (local < n_local)
+        local_c = jnp.where(in_range, local, n_local)         # park OOR at end
+        order = jnp.argsort(local_c)                          # stable
+        se = local_c[order]
+        st = tok_flat[order]
+        sw = w_flat[order]
+        counts = jnp.bincount(local_c, length=n_local + 1)[:n_local]
+        starts = jnp.cumsum(counts) - counts                  # (n_local,)
+        se_c = jnp.minimum(se, n_local - 1)
+        rank = jnp.arange(T * k, dtype=jnp.int32) - starts[se_c]
+        keep = (se < n_local) & (rank >= 0) & (rank < cap)
+        rank_c = jnp.where(keep, rank, 0)
 
-    xe = jnp.zeros((n_local, cap, d), xf.dtype)
-    xe = xe.at[se_c, rank_c].add(
-        jnp.where(keep[:, None], xf[st], 0).astype(xf.dtype))
+        xe = jnp.zeros((n_local, cap, d), xf.dtype)
+        xe = xe.at[se_c, rank_c].add(
+            jnp.where(keep[:, None], xf[st], 0).astype(xf.dtype))
 
     act = jax.nn.gelu if cfg.mlp_activation == "gelu" else jax.nn.silu
-    if cfg.kernels == "pallas":
-        from repro.kernels import ops
-        g = ops.grouped_matmul(xe, p_gate.astype(xf.dtype))
-        u = ops.grouped_matmul(xe, p_up.astype(xf.dtype))
-        h = act(g) * u
-        ye = ops.grouped_matmul(h, p_down.astype(xf.dtype))
-    else:
-        g = jnp.einsum("ecd,edf->ecf", xe, p_gate.astype(xf.dtype))
-        u = jnp.einsum("ecd,edf->ecf", xe, p_up.astype(xf.dtype))
-        h = act(g) * u
-        ye = jnp.einsum("ecf,efd->ecd", h, p_down.astype(xf.dtype))
+    with jax.named_scope("experts"):
+        if cfg.kernels == "pallas":
+            from repro.kernels import ops
+            g = ops.grouped_matmul(xe, p_gate.astype(xf.dtype))
+            u = ops.grouped_matmul(xe, p_up.astype(xf.dtype))
+            h = act(g) * u
+            ye = ops.grouped_matmul(h, p_down.astype(xf.dtype))
+        else:
+            g = jnp.einsum("ecd,edf->ecf", xe, p_gate.astype(xf.dtype))
+            u = jnp.einsum("ecd,edf->ecf", xe, p_up.astype(xf.dtype))
+            h = act(g) * u
+            ye = jnp.einsum("ecf,efd->ecd", h, p_down.astype(xf.dtype))
 
-    gathered = ye[se_c, rank_c] * jnp.where(keep, sw, 0.0)[:, None
-                                                           ].astype(xf.dtype)
-    return jnp.zeros((T, d), xf.dtype).at[st].add(gathered)
+    with jax.named_scope("combine"):
+        gathered = ye[se_c, rank_c] * jnp.where(keep, sw, 0.0)[
+            :, None].astype(xf.dtype)
+        return jnp.zeros((T, d), xf.dtype).at[st].add(gathered)
 
 
 def _router(xf: jax.Array, router_w: jax.Array, cfg: ModelConfig):
@@ -146,6 +149,9 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig
       are psum'd over the expert axis — no data-dependent scatter ever
       crosses a shard boundary (GSPMD cannot shard those; see DESIGN.md S8).
     * **single-shard** fallback (tests, CPU smoke): same dispatch over all E.
+
+    Both run under the named scopes ``router``, ``dispatch``, ``experts``,
+    ``shared`` and ``combine``.
     """
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
@@ -164,12 +170,14 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig
         def local_moe(xl, router_w, wg, wu, wd):
             Bl, Sl, _ = xl.shape
             xf = xl.reshape(Bl * Sl, d)
-            gate_vals, expert_idx, aux = _router(xf, router_w, cfg)
+            with jax.named_scope("router"):
+                gate_vals, expert_idx, aux = _router(xf, router_w, cfg)
             e_lo = jax.lax.axis_index(e_ax) * n_local
             cap = _capacity(Bl * Sl, cfg)
             yf = _dispatch_ffn_combine(xf, wg, wu, wd, gate_vals,
                                        expert_idx, cfg, e_lo, n_local, cap)
-            yf = jax.lax.psum(yf, e_ax)
+            with jax.named_scope("combine"):
+                yf = jax.lax.psum(yf, e_ax)
             aux = jax.lax.pmean(aux, e_ax)
             if b_axes:
                 aux = jax.lax.pmean(aux, b_axes)
@@ -184,7 +192,8 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig
         )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     else:
         xf = x.reshape(B * S, d)
-        gate_vals, expert_idx, aux = _router(xf, p["router"], cfg)
+        with jax.named_scope("router"):
+            gate_vals, expert_idx, aux = _router(xf, p["router"], cfg)
         cap = _capacity(B * S, cfg)
         yf = _dispatch_ffn_combine(xf, p["w_gate"], p["w_up"], p["w_down"],
                                    gate_vals, expert_idx, cfg, 0,
@@ -192,7 +201,8 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig
         y = yf.reshape(B, S, d)
 
     if "shared" in p:
-        y = y + L.mlp(p["shared"], x, cfg)
+        with jax.named_scope("shared"):
+            y = y + L.mlp(p["shared"], x, cfg)
     return constrain(y, ("batch", "seq", "embed")), aux
 
 
@@ -218,14 +228,17 @@ def moe_spec(cfg: ModelConfig) -> Params:
 
 def _moe_block_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
                      kv_cache=None, cache_index=None):
-    h = L.rmsnorm(p["attn_norm"], x, cfg.norm_eps)
-    attn_out, new_cache = L.attention(p["attn"], h, cfg, causal=True,
-                                      kv_cache=kv_cache,
-                                      cache_index=cache_index)
+    with jax.named_scope("attention"):
+        h = L.rmsnorm(p["attn_norm"], x, cfg.norm_eps)
+        attn_out, new_cache = L.attention(p["attn"], h, cfg, causal=True,
+                                          kv_cache=kv_cache,
+                                          cache_index=cache_index)
     x = x + attn_out
-    h = L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps)
-    y, aux = moe_mlp(p["moe"], h, cfg)
-    return x + y, aux, new_cache
+    with jax.named_scope("ffn"):
+        h = L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps)
+        y, aux = moe_mlp(p["moe"], h, cfg)
+        x = x + y
+    return x, aux, new_cache
 
 
 def forward(params: Params, tokens: jax.Array, cfg: ModelConfig
@@ -264,7 +277,9 @@ from .transformer import cache_logical_axes, init_cache  # same cache layout
 def decode_step(params: Params, tokens: jax.Array,
                 cache: Dict[str, jax.Array], cfg: ModelConfig
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    x = L.embed(params["embed"], tokens, cfg)
+    """One decode step, under the dense step's named scopes."""
+    with jax.named_scope("embed"):
+        x = L.embed(params["embed"], tokens, cfg)
     idx = cache["index"]
 
     def body(h, xs):
@@ -273,9 +288,11 @@ def decode_step(params: Params, tokens: jax.Array,
                                          kv_cache=(ck, cv), cache_index=idx)
         return h2, new_kv
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.lm_head(params.get("lm_head", {}), x, cfg,
-                       embed_params=params["embed"])
+    with jax.named_scope("layer_loop"):
+        x, (new_k, new_v) = jax.lax.scan(
+            body, x, (params["blocks"], cache["k"], cache["v"]))
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = L.lm_head(params.get("lm_head", {}), x, cfg,
+                           embed_params=params["embed"])
     return logits, {"k": new_k, "v": new_v, "index": idx + tokens.shape[1]}

@@ -42,13 +42,15 @@ def transformer_spec(cfg: ModelConfig) -> Params:
 
 def block_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
                 kv_cache=None, cache_index=None, causal: bool = True):
-    h = L.rmsnorm(p["attn_norm"], x, cfg.norm_eps)
-    attn_out, new_cache = L.attention(p["attn"], h, cfg, causal=causal,
-                                      kv_cache=kv_cache,
-                                      cache_index=cache_index)
+    with jax.named_scope("attention"):
+        h = L.rmsnorm(p["attn_norm"], x, cfg.norm_eps)
+        attn_out, new_cache = L.attention(p["attn"], h, cfg, causal=causal,
+                                          kv_cache=kv_cache,
+                                          cache_index=cache_index)
     x = x + attn_out
-    h = L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps)
-    x = x + L.mlp(p["mlp"], h, cfg)
+    with jax.named_scope("ffn"):
+        h = L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps)
+        x = x + L.mlp(p["mlp"], h, cfg)
     return x, new_cache
 
 
@@ -119,10 +121,15 @@ def cache_logical_axes() -> Dict[str, Tuple[Optional[str], ...]]:
 def decode_step(params: Params, tokens: jax.Array,
                 cache: Dict[str, jax.Array], cfg: ModelConfig
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode step.  tokens: (B, 1); cache k/v: (L, B, T, nkv, hd)."""
-    x = L.embed(params["embed"], tokens, cfg)
-    if cfg.name.startswith("gemma"):
-        x = x * (cfg.d_model ** 0.5)
+    """One decode step.  tokens: (B, 1); cache k/v: (L, B, T, nkv, hd).
+
+    Its named scopes (``embed``, ``layer_loop``, ``lm_head``; within each
+    block ``attention`` and ``ffn``) are the device trace's stable names
+    for the parts of the step (DESIGN_OBS.md)."""
+    with jax.named_scope("embed"):
+        x = L.embed(params["embed"], tokens, cfg)
+        if cfg.name.startswith("gemma"):
+            x = x * (cfg.d_model ** 0.5)
     idx = cache["index"]
 
     def body(h, xs):
@@ -131,11 +138,13 @@ def decode_step(params: Params, tokens: jax.Array,
                                  kv_cache=(ck, cv), cache_index=idx)
         return h2, new_kv
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache["k"], cache["v"]))
-    x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = L.lm_head(params.get("lm_head", {}), x, cfg,
-                       embed_params=params["embed"])
+    with jax.named_scope("layer_loop"):
+        x, (new_k, new_v) = jax.lax.scan(
+            body, x, (params["blocks"], cache["k"], cache["v"]))
+    with jax.named_scope("lm_head"):
+        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = L.lm_head(params.get("lm_head", {}), x, cfg,
+                           embed_params=params["embed"])
     new_cache = {"k": new_k, "v": new_v, "index": idx + tokens.shape[1]}
     return logits, new_cache
 

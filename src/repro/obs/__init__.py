@@ -2,11 +2,12 @@
 
 Three deliberately-decoupled layers (DESIGN_OBS.md):
 
-* :mod:`repro.obs.trace` — a low-overhead span tracer (context-manager +
-  decorator API) threaded through the whole planning stack and exported as
-  Chrome trace-event JSON (``REPRO_TRACE=<path>`` or
+* :mod:`repro.obs.trace` — a low-overhead span tracer (context-manager
+  API) threaded through the whole planning stack and the serving loop and
+  exported as Chrome trace-event JSON (``REPRO_TRACE=<path>`` or
   ``benchmarks/run.py --trace``), with per-worker span buffers merged
-  across process boundaries by ``repro.parallel.search_exec``;
+  across process boundaries by ``repro.parallel.search_exec``; its spans
+  also land in a JAX profiler capture;
 * :mod:`repro.obs.metrics` — a process-wide counter/gauge/histogram
   registry with labeled series and a JSON snapshot; the planner's phase
   timings, plancache hit/miss/bypass counters, ``lower_jax`` planner
@@ -31,8 +32,11 @@ The serving stack (PR 10) adds four more stdlib-only layers:
   registry plus the ``launch/serve.py --introspect-port`` HTTP endpoint
   (``/metrics``, ``/healthz``, ``/slo``, ``/plans``, ``/tenants``).
 
-``trace``, ``metrics``, ``context``, ``flightrec``, ``slo`` and ``expo``
-are stdlib-only and import nothing from ``repro.core`` (the core planner
+:mod:`repro.obs.scopes` holds the named scopes of the serve step and
+puts each device op of a compiled step in one (from its HLO text).
+
+``trace``, ``metrics``, ``context``, ``flightrec``, ``slo``, ``expo`` and
+``scopes`` are stdlib-only and import nothing from ``repro.core`` (the core planner
 imports *them*); ``explain`` sits above the planner and may import
 everything.
 

@@ -24,6 +24,7 @@ The canonical metric names and label sets live in DESIGN_OBS.md.
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
@@ -317,6 +318,65 @@ def set_gauge(name: str, value: float, **labels: Any) -> None:
 
 def observe(name: str, value: float, **labels: Any) -> None:
     REGISTRY.histogram(name).observe(value, **labels)
+
+
+# --------------------------------------------------------- JAX compilations
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+class CompileWatch:
+    """``jax_compiles_total{stage}``, fed by ``jax.monitoring``: one
+    increment for each program JAX hands its backend, with ``stage`` =
+    ``cache_load`` where the persistent compilation cache served it and
+    ``backend`` where the backend compiled it.  A call of a program this
+    process already holds sends no event, so nothing is counted per step.
+
+    It also keeps the host time (``time.perf_counter``) and program name
+    of the last ``keep`` compilations, so that a caller can count those
+    inside an interval of its own (:meth:`between`)."""
+
+    def __init__(self, keep: int = 256) -> None:
+        self.log: collections.deque = collections.deque(maxlen=keep)
+        self.installed = False
+        self._hit = threading.local()       # a cache hit awaiting its event
+        self._lock = threading.Lock()
+
+    def install(self) -> None:
+        """Register the listeners (once per process; JAX keeps them)."""
+        with self._lock:
+            if self.installed:
+                return
+            self.installed = True
+        from jax import monitoring          # lazy: the registry stays stdlib
+        monitoring.register_event_listener(self._on_event)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def _on_event(self, event: str, **kwargs: Any) -> None:
+        if event == _CACHE_HIT:
+            self._hit.pending = True
+
+    def _on_duration(self, event: str, seconds: float,
+                     **kwargs: Any) -> None:
+        if event != _BACKEND_COMPILE:
+            return
+        # a cache load sends its hit, then the same event as a compile
+        stage = ("cache_load" if getattr(self._hit, "pending", False)
+                 else "backend")
+        self._hit.pending = False
+        inc("jax_compiles_total", stage=stage)
+        self.log.append((time.perf_counter(), stage,
+                         str(kwargs.get("fun_name", ""))))
+
+    def between(self, start: float, end: float
+                ) -> List[Tuple[float, str, str]]:
+        """``(time, stage, program)`` of the kept compilations that ended
+        within ``[start, end]`` (``time.perf_counter`` seconds)."""
+        return [c for c in list(self.log) if start <= c[0] <= end]
+
+
+COMPILES = CompileWatch()
+watch_compiles = COMPILES.install
 
 
 # ------------------------------------------------------- snapshot utilities

@@ -1,8 +1,8 @@
-"""Structured span tracer for the planning stack (Chrome trace-event JSON).
+"""Structured span tracer for the planner and the serving loop (Chrome
+trace-event JSON).
 
 Disabled by default with near-zero cost when off: :func:`span` is one
-attribute load plus returning a shared no-op context manager, and
-:func:`traced`-wrapped functions pay one ``if`` per call.  Enabled via
+attribute load plus returning a shared no-op context manager.  Enabled via
 ``REPRO_TRACE=<path>`` (the file is written at interpreter exit, and by
 :func:`write` explicitly), :func:`enable`, or ``benchmarks/run.py
 --trace``.
@@ -21,19 +21,25 @@ existing chunk-result path, and the parent :func:`ingest`\\ s them with the
 worker's ``pid``/``tid`` preserved — the cross-process merge protocol
 documented in DESIGN_OBS.md.
 
+In a process that has imported JAX, each span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so a profiler capture shows
+it on the host timeline of the device trace, on the device's clock.  JAX
+is never imported here: a process that only plans stays free of it.
+
 Invariant: the tracer only *observes* (two clock reads and a dict append
-per span).  It never feeds anything back into planning, so traced and
-untraced searches select bit-identical plans (``tests/test_obs.py``).
+per span).  It never feeds anything back into planning or serving, so
+traced and untraced searches select bit-identical plans and serve
+bit-identical ids (``tests/test_obs.py``, ``tests/test_serve_trace.py``).
 """
 from __future__ import annotations
 
 import atexit
-import functools
 import json
 import os
+import sys
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 TRACE_ENV = "REPRO_TRACE"
 
@@ -120,20 +126,28 @@ def _record(name: str, cat: str, t0: float, t1: float,
 
 class _Span:
     """Active span context manager (only constructed when tracing is on)."""
-    __slots__ = ("name", "cat", "args", "t0")
+    __slots__ = ("name", "cat", "args", "t0", "annotation")
 
     def __init__(self, name: str, cat: str, args: Dict[str, Any]) -> None:
         self.name = name
         self.cat = cat
         self.args = args
         self.t0 = 0.0
+        self.annotation = None
 
     def __enter__(self) -> "_Span":
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self.annotation = jax.profiler.TraceAnnotation(self.name)
+            self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        _record(self.name, self.cat, self.t0, time.perf_counter(), self.args)
+        t1 = time.perf_counter()
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
+        _record(self.name, self.cat, self.t0, t1, self.args)
 
 
 class _NullSpan:
@@ -155,26 +169,6 @@ def span(name: str, cat: str = "planner", **args: Any):
     if not _STATE.on:
         return _NULL
     return _Span(name, cat, args)
-
-
-def traced(name: Optional[str] = None, cat: str = "planner"
-           ) -> Callable[[Callable], Callable]:
-    """Decorator form of :func:`span` (span name defaults to the function's
-    qualified name)."""
-    def deco(fn: Callable) -> Callable:
-        sname = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a, **kw):
-            if not _STATE.on:
-                return fn(*a, **kw)
-            t0 = time.perf_counter()
-            try:
-                return fn(*a, **kw)
-            finally:
-                _record(sname, cat, t0, time.perf_counter(), None)
-        return wrapper
-    return deco
 
 
 # ------------------------------------------------------- cross-process merge

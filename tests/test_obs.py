@@ -6,6 +6,7 @@ explain CLI renders kernel and pipeline cells, and golden regeneration is
 refused while tracing."""
 import json
 import os
+import time
 
 import pytest
 
@@ -61,17 +62,71 @@ def test_span_records_complete_events():
 
 
 def test_traced_decorator_and_drain():
-    @trace.traced("decorated.fn", cat="t")
-    def f(x):
-        return x + 1
-
-    assert f(1) == 2
-    assert trace.events() == []          # disabled: zero events
+    with trace.span("drained.fn", cat="t"):
+        pass
+    assert trace.drain() == []           # disabled: zero events
     trace.enable()
-    assert f(2) == 3
+    with trace.span("drained.fn", cat="t"):
+        pass
     drained = trace.drain()
-    assert [e["name"] for e in drained] == ["decorated.fn"]
+    assert [e["name"] for e in drained] == ["drained.fn"]
     assert trace.events() == []          # drain clears
+
+
+def _profiled(tmp_path, body):
+    """Names of the events of a profiler capture of ``body()``, each with
+    the name of the host line (thread) it is on."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    return {e.name: line.name for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events}
+
+
+def test_span_lands_on_the_profiler_timeline(tmp_path):
+    import jax
+
+    def body():
+        assert trace.span("obs.off") is trace.span("obs.off2")
+        with trace.span("obs.off"):      # tracing off: no annotation
+            pass
+        trace.enable()
+        with jax.profiler.TraceAnnotation("obs.marker"):
+            with trace.span("obs.on", cat="t"):
+                pass
+
+    lines = _profiled(tmp_path, body)
+    assert lines["obs.on"] == lines["obs.marker"]     # the caller's thread
+    assert "obs.off" not in lines
+    assert [e["name"] for e in trace.events()] == ["obs.on"]
+
+
+def test_compile_counter_counts_new_programs_only():
+    import jax
+    import jax.numpy as jnp
+
+    def seven(x):
+        return x * 7 + 1
+
+    metrics.watch_compiles()
+    metrics.watch_compiles()             # once per process
+    f, x = jax.jit(seven), jnp.ones(5)
+    total = lambda: metrics.counter("jax_compiles_total").total()
+    before = total()
+    f(x).block_until_ready()
+    assert total() == before + 1
+    assert metrics.COMPILES.log[-1][2] == "jit(seven)"
+    t0 = time.perf_counter()
+    f(x).block_until_ready()             # a cached call: no event
+    assert total() == before + 1
+    assert metrics.COMPILES.between(t0, time.perf_counter()) == []
 
 
 def test_ingest_preserves_worker_identity():

@@ -99,3 +99,34 @@ def test_qwen_full_width_decode_step_fits_hbm(one_chip):
         params, tokens, cache).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 6e9          # bf16 weights, 3.1B
+
+
+def test_qwen_decode_step_ops_all_have_scopes(one_chip):
+    """Every device op of the benchmark's qwen2.5-3b decode step (full
+    width, batch 16, slab 1024) lies in a named scope of the step, and the
+    float32 copies of a layer's whole K and V slab lie in
+    ``attention/core``, the upcast the contract puts there."""
+    import re
+    from repro.launch.serve import serving_config
+    from repro.obs import scopes
+    from repro.models import build_model
+    cfg = serving_config("qwen2.5-3b")
+    api = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(api.abstract_params())
+    cache = on_chip(jax.eval_shape(lambda: api.init_cache(cfg, 16, 1024)))
+    tokens = jax.ShapeDtypeStruct((16, 1), jnp.int32, sharding=one_chip)
+    text = jax.jit(api.decode_step, donate_argnums=(2,)).lower(
+        params, tokens, cache).compile().as_text()
+    assert scopes.uncovered(text) == []
+    found = scopes.scope_map(text)
+    assert {"layer_loop", "attention/core", "ffn", "lm_head"} <= set(
+        found.values())
+    slab_f32 = [n for n in re.findall(
+        r"^\s*%([\w.\-]+) = f32\[16,1024,2,128\]", text, re.M)
+        if n in found]
+    assert slab_f32 and {found[n] for n in slab_f32} == {"attention/core"}
