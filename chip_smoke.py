@@ -7,9 +7,10 @@ One chip, in one process:
 
 (a) device check: JAX's first device must be a TPU (no CPU fallback);
 (b) serve qwen2.5-3b at its published width (batch 4, prompt 128, 32 new
-    tokens) through ``repro.launch.serve``, and check the cached
-    token-by-token logits and greedy ids against a float32 full-sequence
-    forward (``api.logits_fn``) at highest matmul precision;
+    tokens) through ``repro.launch.serve``, the prompt fed in chunks and
+    the ids decoded one step each, and check the cached logits and greedy
+    ids against a float32 full-sequence forward (``api.logits_fn``) at
+    highest matmul precision;
 (c) run each kernel of ``kernels/ops.py`` at real widths with
     planner-chosen blocks, check that it compiled to a Mosaic kernel, and
     compare it with ``kernels/ref.py``.

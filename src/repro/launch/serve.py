@@ -8,6 +8,13 @@ mesh planner's decode ranking is printed; the sharded serve step under that
 plan is ``train/serve_step.jit_serve_step``.  Set-up (planning, weight init,
 compilation) is reported apart from prefill and decode time.
 
+One jitted serve step does both phases.  The prompt goes through it in
+chunks whose widths come from the fixed power-of-two ladder
+:data:`PREFILL_WIDTHS` (a prompt of 227 tokens in five executions,
+128 + 64 + 32 + 2 + 1), where the cache is a KV slab alone; a cache that
+holds recurrent state takes the prompt one token a step.  Decoding feeds
+one token a step.
+
 Serving-layer observability (DESIGN_OBS.md): ``--introspect-port`` starts
 a read-only HTTP endpoint (``/metrics`` Prometheus text, ``/healthz``,
 ``/slo``, ``/plans``, ``/tenants``) before any planning happens;
@@ -20,7 +27,7 @@ import argparse
 import dataclasses
 import functools
 import time
-from typing import Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -216,7 +223,9 @@ def synthetic_prompts(cfg: ModelConfig, batch: int, prompt_len: int,
 class GreedyRun:
     """One greedy generation: generated ids (B, n), the logits each id was
     picked from (B, n, vocab; row 0 is the last prompt position), and the
-    host-clock seconds of each phase (each ends in ``block_until_ready``)."""
+    host-clock seconds of each phase, each ending in ``block_until_ready``:
+    ``prefill_s`` feeds the prompt in chunks and picks the first id,
+    ``decode_s`` the other n - 1 ids, one step each."""
     generated: jax.Array
     logits: jax.Array
     prefill_s: float
@@ -230,17 +239,77 @@ def _pick(logits: jax.Array, vocab_size: int):
     return row, jnp.argmax(row, axis=-1)[:, None].astype(jnp.int32)
 
 
+# Chunk widths of the prompt phase, largest first: a prompt is fed as the
+# binary decomposition of its length over them.
+PREFILL_WIDTHS = (128, 64, 32, 16, 8, 4, 2, 1)
+
+
+def chunk_widths(cache) -> Tuple[int, ...]:
+    """The widths of :data:`PREFILL_WIDTHS` that a serve step over
+    ``cache`` can take: those that fit its slab where the cache is a KV
+    slab alone (keys ``k``, ``v``, ``index``); else one token, since a
+    chunk cannot advance recurrent state."""
+    if set(cache) != {"k", "v", "index"}:
+        return (1,)
+    slab = cache["k"].shape[2]
+    return tuple(w for w in PREFILL_WIDTHS if w <= slab)
+
+
+def prompt_chunks(n: int, widths) -> List[Tuple[int, int]]:
+    """(start, width) of each chunk that feeds ``n`` prompt positions: the
+    widest of ``widths`` (largest first, ending in 1) that still fits,
+    again and again: the binary decomposition of ``n`` over power-of-two
+    widths, after as many top-width chunks as ``n`` holds."""
+    chunks, t = [], 0
+    for w in widths:
+        while n - t >= w:
+            chunks.append((t, w))
+            t += w
+    return chunks
+
+
+@dataclasses.dataclass(frozen=True)
+class ChunkedStep:
+    """The serve step compiled once per chunk width.  Called like the
+    step, it runs the executable for ``tokens.shape[1]`` positions; every
+    one returns logits (B, 1, vocab)."""
+    by_width: Dict[int, Any]
+
+    @property
+    def widths(self) -> Tuple[int, ...]:
+        return tuple(sorted(self.by_width, reverse=True))
+
+    def __call__(self, params, tokens, cache):
+        return self.by_width[tokens.shape[1]](params, tokens, cache)
+
+    def as_text(self) -> str:
+        """The one-token executable's HLO: the decode phase's step."""
+        return self.by_width[1].as_text()
+
+
 def compile_greedy(step, params, tokens, cache, vocab_size: int):
-    """AOT-compile ``step`` (a jitted ``decode_step``) and the greedy pick
-    for these arguments, so that the timed loop compiles nothing.
-    Returns ``(decode, pick)``.  Also re-reads ``REPRO_TRACE`` and starts
-    counting JAX's compilations (``jax_compiles_total``)."""
+    """AOT-compile ``step`` (a jitted ``decode_step``) for these arguments
+    at every chunk width ``cache`` takes (:func:`chunk_widths`; ``tokens``
+    is the one-token width), and the greedy pick, and run each width once
+    on a scratch cache shaped like ``cache``.  So the timed loop compiles
+    nothing and runs no executable for the first time.  Returns
+    ``(decode, pick)``, ``decode`` a :class:`ChunkedStep`.  Also re-reads
+    ``REPRO_TRACE`` and starts counting JAX's compilations
+    (``jax_compiles_total``)."""
     trace.refresh_from_env()
     metrics.watch_compiles()
-    decode = step.lower(params, tokens, cache).compile()
+    scratch = jax.tree.map(jnp.zeros_like, cache)
+    by_width = {}
+    for w in chunk_widths(cache):
+        ids = tokens if w == 1 else np.zeros((tokens.shape[0], w),
+                                             tokens.dtype)
+        by_width[w] = step.lower(params, ids, cache).compile()
+        # dispatched, so the device runs it while the next width compiles
+        _, scratch = by_width[w](params, ids, scratch)
     pick = jax.jit(functools.partial(_pick, vocab_size=vocab_size)).lower(
-        decode.out_info[0]).compile()
-    return decode, pick
+        by_width[1].out_info[0]).compile()
+    jax.block_until_ready(scratch)
+    return ChunkedStep(by_width), pick
 
 
 def _loop_spans():
@@ -255,22 +324,26 @@ def _loop_spans():
 
 def greedy_generate(decode, pick, params, prompts, cache,
                     n_tokens: int) -> GreedyRun:
-    """Feed the prompt (host ids, B x P) token by token through ``decode``,
-    then greedy-decode ``n_tokens`` ids; ``decode``/``pick`` come from
-    :func:`compile_greedy`.
+    """Feed the prompt (host ids, B x P) through ``decode`` in chunks
+    (:func:`prompt_chunks` over ``decode.widths``), then greedy-decode
+    ``n_tokens`` ids one step each; ``decode``/``pick`` come from
+    :func:`compile_greedy`.  Counts each chunk in
+    ``serve_prefill_chunks_total{width}``.
 
     Spans (category ``serve``, DESIGN_OBS.md): ``serve.prefill`` and
     ``serve.decode`` cover the two phases, ``serve.step`` each feed and
-    dispatch, ``serve.sync`` each wait on the device, ``serve.collect``
-    the closing concatenate and stack.  They are recorded while tracing
-    is on, and reach a running JAX profiler capture either way."""
+    dispatch (one a chunk, one a decoded id), ``serve.sync`` each wait on
+    the device, ``serve.collect`` the closing concatenate and stack.  They
+    are recorded while tracing is on, and reach a running JAX profiler
+    capture either way."""
     prompts = np.asarray(prompts)
     span = _loop_spans()
     t0 = time.perf_counter()
     with span("serve.prefill"):
-        for t in range(prompts.shape[1]):
+        for t, w in prompt_chunks(prompts.shape[1], decode.widths):
             with span("serve.step"):
-                logits, cache = decode(params, prompts[:, t:t + 1], cache)
+                logits, cache = decode(params, prompts[:, t:t + w], cache)
+            metrics.inc("serve_prefill_chunks_total", width=w)
         row, tok = pick(logits)
         with span("serve.sync"):
             jax.block_until_ready(tok)

@@ -173,7 +173,9 @@ def _sdpa_xla(q, k, v, causal: bool, sm_scale: float,
 
 def _sdpa_xla_dense(q, k, v, causal: bool, sm_scale: float,
                     kv_valid_len: Optional[jax.Array] = None) -> jax.Array:
-    """q: (B,S,H,D), k/v: (B,T,H,D) -> (B,S,H,D)."""
+    """q: (B,S,H,D), k/v: (B,T,H,D) -> (B,S,H,D).  ``kv_valid_len``: the
+    number of leading keys every query sees (a scalar), or each query's
+    number (shape (S,))."""
     s = jnp.einsum("bshd,bthd->bhst", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
     if causal:
@@ -184,7 +186,12 @@ def _sdpa_xla_dense(q, k, v, causal: bool, sm_scale: float,
     if kv_valid_len is not None:
         T = s.shape[-1]
         ki = jnp.arange(T)
-        s = jnp.where((ki < kv_valid_len)[None, None, None, :], s, -jnp.inf)
+        if jnp.ndim(kv_valid_len):     # one length per query: (S,)
+            s = jnp.where((ki[None, :] < kv_valid_len[:, None])[None, None],
+                          s, -jnp.inf)
+        else:
+            s = jnp.where((ki < kv_valid_len)[None, None, None, :], s,
+                          -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhst,bthd->bshd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
@@ -216,7 +223,8 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
 
     * train/prefill: ``kv_cache is None`` — full self (or cross) attention.
     * decode: ``kv_cache=(k, v)`` of shape (B, T, nkv, hd); the current
-      token's k/v are inserted at ``cache_index``.
+      tokens' k/v are inserted at ``cache_index``, and query i of the S
+      tokens sees cache positions up to ``cache_index + i``.
     * cross-attention: ``kv_input`` projects k/v from another sequence, or
       ``precomputed_kv`` supplies already-projected (k, v) (cached cross
       attention during decode).
@@ -275,7 +283,11 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig, *,
             out = _sdpa_pallas(q, kr, vr, is_causal, sm_scale)
         else:
             cached = kv_cache is not None and cache_index is not None
-            valid = cache_index + S if cached else None
+            valid = None
+            if cached:
+                # query i of a chunk sees the cache up to its own position
+                valid = (cache_index + S if S == 1
+                         else cache_index + 1 + jnp.arange(S))
             out = _sdpa_xla(q, kr, vr, is_causal, sm_scale,
                             kv_valid_len=valid)
         out = constrain(out, ("batch", "seq", "q_heads", "head_dim"))

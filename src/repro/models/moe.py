@@ -44,10 +44,14 @@ def moe_mlp_spec(cfg: ModelConfig) -> Params:
     return spec
 
 
-def _capacity(tokens: int, cfg: ModelConfig) -> int:
+def _capacity(tokens: int, cfg: ModelConfig, drop_free: bool = False
+              ) -> int:
+    """Slots per expert for ``tokens`` tokens.  ``drop_free`` (serving)
+    gives every expert room for all of them, so no token is dropped."""
     cap = int(math.ceil(tokens * cfg.experts_per_token * cfg.capacity_factor
                         / cfg.n_experts))
-    return max(8, -(-cap // 8) * 8)      # round up to a multiple of 8
+    cap = max(8, -(-cap // 8) * 8)       # round up to a multiple of 8
+    return max(cap, tokens) if drop_free else cap
 
 
 def _dispatch_ffn_combine(xf: jax.Array, p_gate, p_up, p_down,
@@ -138,9 +142,10 @@ def _ep_axes() -> Tuple[Optional[Tuple[str, ...]], Optional[str]]:
     return b_axes, e_ax
 
 
-def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig
-            ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out, aux_loss).
+def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig,
+            drop_free: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, d) -> (out, aux_loss).  ``drop_free``: expert capacity
+    for every token (:func:`_capacity`), as serving needs.
 
     Two execution paths:
     * **shard_map expert-parallel** (active when the current ShardingPlan maps
@@ -173,7 +178,7 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig
             with jax.named_scope("router"):
                 gate_vals, expert_idx, aux = _router(xf, router_w, cfg)
             e_lo = jax.lax.axis_index(e_ax) * n_local
-            cap = _capacity(Bl * Sl, cfg)
+            cap = _capacity(Bl * Sl, cfg, drop_free)
             yf = _dispatch_ffn_combine(xf, wg, wu, wd, gate_vals,
                                        expert_idx, cfg, e_lo, n_local, cap)
             with jax.named_scope("combine"):
@@ -194,7 +199,7 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig
         xf = x.reshape(B * S, d)
         with jax.named_scope("router"):
             gate_vals, expert_idx, aux = _router(xf, p["router"], cfg)
-        cap = _capacity(B * S, cfg)
+        cap = _capacity(B * S, cfg, drop_free)
         yf = _dispatch_ffn_combine(xf, p["w_gate"], p["w_up"], p["w_down"],
                                    gate_vals, expert_idx, cfg, 0,
                                    cfg.n_experts, cap)
@@ -236,7 +241,7 @@ def _moe_block_apply(p: Params, x: jax.Array, cfg: ModelConfig, *,
     x = x + attn_out
     with jax.named_scope("ffn"):
         h = L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps)
-        y, aux = moe_mlp(p["moe"], h, cfg)
+        y, aux = moe_mlp(p["moe"], h, cfg, drop_free=kv_cache is not None)
         x = x + y
     return x, aux, new_cache
 
@@ -277,7 +282,9 @@ from .transformer import cache_logical_axes, init_cache  # same cache layout
 def decode_step(params: Params, tokens: jax.Array,
                 cache: Dict[str, jax.Array], cfg: ModelConfig
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode step, under the dense step's named scopes."""
+    """One serving step, under the dense step's named scopes: S = 1
+    decodes, S > 1 feeds a prompt chunk (as ``transformer.decode_step``).
+    No token is dropped by expert capacity."""
     with jax.named_scope("embed"):
         x = L.embed(params["embed"], tokens, cfg)
     idx = cache["index"]
@@ -292,6 +299,8 @@ def decode_step(params: Params, tokens: jax.Array,
         x, (new_k, new_v) = jax.lax.scan(
             body, x, (params["blocks"], cache["k"], cache["v"]))
     with jax.named_scope("lm_head"):
+        if tokens.shape[1] > 1:
+            x = x[:, -1:]
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = L.lm_head(params.get("lm_head", {}), x, cfg,
                            embed_params=params["embed"])

@@ -121,7 +121,11 @@ def cache_logical_axes() -> Dict[str, Tuple[Optional[str], ...]]:
 def decode_step(params: Params, tokens: jax.Array,
                 cache: Dict[str, jax.Array], cfg: ModelConfig
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode step.  tokens: (B, 1); cache k/v: (L, B, T, nkv, hd).
+    """One serving step.  tokens: (B, S); cache k/v: (L, B, T, nkv, hd).
+
+    S = 1 decodes one token; S > 1 feeds a chunk of the prompt, causal
+    within the chunk, and returns the logits of its last position only, so
+    the logits are (B, 1, V) whatever S is.
 
     Its named scopes (``embed``, ``layer_loop``, ``lm_head``; within each
     block ``attention`` and ``ffn``) are the device trace's stable names
@@ -142,14 +146,10 @@ def decode_step(params: Params, tokens: jax.Array,
         x, (new_k, new_v) = jax.lax.scan(
             body, x, (params["blocks"], cache["k"], cache["v"]))
     with jax.named_scope("lm_head"):
+        if tokens.shape[1] > 1:
+            x = x[:, -1:]
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = L.lm_head(params.get("lm_head", {}), x, cfg,
                            embed_params=params["embed"])
     new_cache = {"k": new_k, "v": new_v, "index": idx + tokens.shape[1]}
     return logits, new_cache
-
-
-def prefill(params: Params, tokens: jax.Array, cache: Dict[str, jax.Array],
-            cfg: ModelConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Fill the cache with a full prompt (teacher-forced pass)."""
-    return decode_step(params, tokens, cache, cfg)

@@ -13,6 +13,7 @@ from repro.models import build_model
 from repro.obs import scopes, trace
 
 B, P, N, SLAB = 2, 3, 4, 16
+CHUNKS = len(serve.prompt_chunks(P, serve.PREFILL_WIDTHS))    # 2 + 1
 
 
 @pytest.fixture(autouse=True)
@@ -64,8 +65,8 @@ def test_spans_change_nothing_served_and_count_the_steps(served):
     (prefill,), (decode,) = _spans("serve.prefill"), _spans("serve.decode")
     inside = lambda phase: [s for s in steps
                             if phase[0] <= s[0] and s[1] <= phase[1]]
-    assert len(inside(prefill)) == P and len(inside(decode)) == N - 1
-    assert len(steps) == P + N - 1
+    assert len(inside(prefill)) == CHUNKS and len(inside(decode)) == N - 1
+    assert len(steps) == CHUNKS + N - 1
     assert len(_spans("serve.sync")) == 2
     (collect,) = _spans("serve.collect")
     assert collect[0] >= decode[1]
@@ -90,7 +91,7 @@ def test_loop_spans_reach_a_capture_with_tracing_off(served, tmp_path):
             for e in line.events:
                 names.setdefault(e.name, []).append(line.name)
     (window_line,) = names["test.window"]
-    assert names["serve.step"] == [window_line] * (P + N - 1)
+    assert names["serve.step"] == [window_line] * (CHUNKS + N - 1)
     for name in ("serve.prefill", "serve.decode", "serve.collect"):
         assert names[name] == [window_line]
     assert len(names["serve.sync"]) == 2

@@ -101,6 +101,37 @@ def test_qwen_full_width_decode_step_fits_hbm(one_chip):
     assert mem.argument_size_in_bytes > 6e9          # bf16 weights, 3.1B
 
 
+# what a v5e chip's 16 GB leave to a program (the runtime keeps the rest)
+V5E_USABLE_HBM = 15.75e9
+
+
+def test_qwen_top_prompt_chunk_fits_hbm(one_chip):
+    """The serve step at the top width of the prompt ladder, as the
+    benchmark's qwen2.5-3b cell feeds a prompt (full width, batch 16, 128
+    positions, slab 1024), compiles for one chip, and its arguments plus
+    temporaries fit the chip's HBM."""
+    from repro.launch.serve import PREFILL_WIDTHS, serving_config
+    from repro.models import build_model
+    cfg = serving_config("qwen2.5-3b")
+    api = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    width = PREFILL_WIDTHS[0]
+    assert width == 128
+    params = on_chip(api.abstract_params())
+    cache = on_chip(jax.eval_shape(lambda: api.init_cache(cfg, 16, 1024)))
+    tokens = jax.ShapeDtypeStruct((16, width), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(api.decode_step, donate_argnums=(2,)).lower(
+        params, tokens, cache).compile()
+    assert compiled.out_info[0].shape == (16, 1, cfg.padded_vocab)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < V5E_USABLE_HBM)
+
+
 def test_qwen_decode_step_ops_all_have_scopes(one_chip):
     """Every device op of the benchmark's qwen2.5-3b decode step (full
     width, batch 16, slab 1024) lies in a named scope of the step, and the
