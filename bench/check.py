@@ -75,7 +75,7 @@ def _forward(family: str, cfg_json: str, control: Optional[str]):
     import jax.numpy as jnp
     from bench import reference
     cfg = json.loads(cfg_json)
-    fwd = reference.FAMILIES[family].forward
+    fwd = reference.family({"family": family}).forward
 
     def gaps(w, tokens, rows, served):
         """The gap of each served id, or (control) of the id that the

@@ -5,16 +5,16 @@ A step feeds one token to each of ``batch`` sequences whose caches hold
 implements it:
 
 - FLOPs: two per multiply-add of every weight the step's tokens meet, plus
-  attention's q.k and p.v over the filled positions of each query head.
+  attention's q.k and p.v over the filled positions.
 - Bytes (bfloat16, two a value): every weight the step needs read once
   (the embedding only in the rows the tokens pick, unless the head is tied
-  to it), the keys and values of the ``filled - 1`` earlier positions read
-  and the new ones written, at key/value-head width, and the logits
-  written.
-- For a mixture of experts, the routed experts' weights count for the
-  expected number of distinct experts that ``batch`` tokens hit under
-  uniform routing, E(1 - (1 - k/E)^batch); the shared experts, the router
-  and everything outside the experts count whole.
+  to it), the cache of the ``filled - 1`` earlier positions read and the
+  new one written, and the logits written.
+
+The decoder blocks' share is the family's (``block_counts`` of its
+``bench/reference/<family>.py``: which weights a step reads and uses, what
+a position's cache holds, and attention's FLOPs a position); the
+embedding, head and logits are every family's and are counted here.
 
 Never what an implementation happens to move: reading an unfilled cache
 or an expert no token chose is not needed work.
@@ -23,44 +23,28 @@ from __future__ import annotations
 
 from typing import Any, Dict, Tuple
 
+from bench import reference
+
 BYTES = 2      # bfloat16
-
-
-def _attention_weights(cfg: Dict[str, Any]) -> int:
-    d, nh, nkv = (cfg["hidden_size"], cfg["num_attention_heads"],
-                  cfg["num_key_value_heads"])
-    hd = d // nh
-    return 2 * d * nh * hd + 2 * d * nkv * hd
 
 
 def step_counts(cfg: Dict[str, Any], batch: int, filled: int
                 ) -> Tuple[float, float]:
     """(FLOPs, bytes) of one step of ``batch`` tokens over caches of
     ``filled`` positions."""
-    d, L, V = cfg["hidden_size"], cfg["num_hidden_layers"], cfg["vocab_size"]
-    nh, nkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd = d // nh
-    attn = _attention_weights(cfg)
-    bias = (nh + 2 * nkv) * hd if cfg.get("qkv_bias") else 0
-    norms = 2 * d
-    if cfg["family"] == "dense":
-        mlp_read = mlp_used = 3 * d * cfg["intermediate_size"]
-    elif cfg["family"] == "moe":
-        E, k = cfg["n_routed_experts"], cfg["num_experts_per_tok"]
-        expert = 3 * d * cfg["moe_intermediate_size"]
-        shared = expert * cfg["n_shared_experts"]
-        hit = E * (1.0 - (1.0 - k / E) ** batch)
-        mlp_read = d * E + shared + hit * expert
-        mlp_used = d * E + shared + k * expert
-    else:
-        raise ValueError(cfg["family"])
+    fam = reference.family(cfg)
+    if not hasattr(fam, "block_counts"):
+        raise ValueError(f"bench/reference/{cfg['family']}.py has no "
+                         f"block_counts: its step's work is not counted")
+    blocks = fam.block_counts(cfg, batch)
+    d, V = cfg["hidden_size"], cfg["vocab_size"]
     head = d * V
-    flops = 2.0 * batch * (L * (attn + mlp_used) + head)
-    flops += 2.0 * 2 * batch * L * nh * hd * filled
-    weights = L * (attn + bias + norms + mlp_read) + d + head
+    flops = 2.0 * batch * (blocks.weights_used + head)
+    flops += batch * blocks.attention_flops * filled
+    weights = blocks.weights_read + d + head           # + final norm
     if not cfg["tie_word_embeddings"]:
         weights += batch * d                   # embedding rows looked up
-    kv = 2 * batch * L * nkv * hd * filled     # filled keys and values
+    kv = batch * blocks.cache_values * filled  # filled cache positions
     logits = batch * V
     return flops, float(BYTES * (weights + kv + logits))
 

@@ -21,7 +21,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from bench import loadgen, weights
+from bench import loadgen, reference, weights
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -29,21 +29,15 @@ STEP_NAME = "serve_decode_step"          # the decode step's stable name
 TRACED_BATCH = 1                         # index of the batch a trace covers
 WINDOW_SPAN = "bench.traced_batch"
 
-# configuration file key -> ModelConfig field, by family
-MODEL_KEYS = {
-    "common": {"vocab_size": "vocab_size", "hidden_size": "d_model",
+# configuration file key -> ModelConfig field, for every family; a family
+# adds its own (``MODEL_KEYS`` of its ``bench/reference/<family>.py``)
+COMMON_KEYS = {"vocab_size": "vocab_size", "hidden_size": "d_model",
                "num_hidden_layers": "n_layers",
                "num_attention_heads": "n_heads",
                "num_key_value_heads": "n_kv_heads",
                "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
                "tie_word_embeddings": "tie_embeddings",
-               "hidden_act": "mlp_activation"},
-    "dense": {"intermediate_size": "d_ff", "qkv_bias": "qkv_bias"},
-    "moe": {"moe_intermediate_size": "moe_d_ff",
-            "n_routed_experts": "n_experts",
-            "n_shared_experts": "n_shared_experts",
-            "num_experts_per_tok": "experts_per_token"},
-}
+               "hidden_act": "mlp_activation"}
 
 
 @dataclasses.dataclass
@@ -71,14 +65,19 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
                 load_json(BENCH / "traffic" / f"{w['traffic']}.json"))
 
 
+def model_keys(model: Dict[str, Any]) -> Dict[str, str]:
+    """Configuration file key -> ModelConfig field, for ``model``'s
+    family."""
+    return {**COMMON_KEYS, **reference.family(model).MODEL_KEYS}
+
+
 def model_config(model: Dict[str, Any]):
     """The program's serving config for this configuration file: the
     architecture's own, with every size the file states put over it."""
     from repro.launch import serve
     cfg = serve.serving_config(model["arch"])
-    keys = {**MODEL_KEYS["common"], **MODEL_KEYS[model["family"]]}
-    return dataclasses.replace(cfg, **{field: model[k]
-                                       for k, field in keys.items()})
+    return dataclasses.replace(cfg, **{field: model[k] for k, field
+                                       in model_keys(model).items()})
 
 
 @dataclasses.dataclass

@@ -1,5 +1,5 @@
-"""What the dense and MoE references share: the seeded weights, and plain
-float32 layers.
+"""What the dense and MoE references share: the seeded weights, plain
+float32 layers, and the work of grouped-query attention in a serving step.
 
 The weights are the benchmark's own: a run serves them and the reference
 reads them, and neither takes them from the program.  One leaf per tensor,
@@ -23,7 +23,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +74,29 @@ def attention_layout(cfg: Dict[str, Any], n: int) -> Dict[str, Leaf]:
                    bk=Leaf((n, nkv, hd), "zeros"),
                    bv=Leaf((n, nkv, hd), "zeros"))
     return out
+
+
+class BlockCounts(NamedTuple):
+    """The work of a serving step in all of a model's decoder blocks, in
+    values and FLOPs (``bench/counts.py`` says what counts as needed)."""
+    weights_read: float     # weight values the step reads
+    weights_used: float     # weight values each token multiplies
+    cache_values: int       # cache values a position of one sequence holds
+    attention_flops: int    # q.k and p.v FLOPs a token spends per position
+
+
+def gqa_block_counts(cfg: Dict[str, Any]) -> Tuple[int, int, int, int]:
+    """One block's grouped-query attention and its two norms, as
+    ``BlockCounts``'s four numbers: weights read (with the q/k/v bias),
+    weights used, a position's keys and values, and a token's FLOPs per
+    position."""
+    d, nh, nkv = (cfg["hidden_size"], cfg["num_attention_heads"],
+                  cfg["num_key_value_heads"])
+    hd = d // nh
+    attn = 2 * d * nh * hd + 2 * d * nkv * hd
+    bias = (nh + 2 * nkv) * hd if cfg.get("qkv_bias") else 0
+    norms = 2 * d
+    return attn + bias + norms, attn, 2 * nkv * hd, 2 * 2 * nh * hd
 
 
 def mlp_layout(n: int, d: int, f: int) -> Dict[str, Leaf]:

@@ -10,6 +10,9 @@ import jax.numpy as jnp
 
 from . import common as C
 
+MODEL_KEYS = {"intermediate_size": "d_ff", "qkv_bias": "qkv_bias"}
+TEST_CUT = {"n_layers": 4}
+
 
 def layout(cfg: Dict[str, Any]) -> Dict[str, Any]:
     n, d = cfg["num_hidden_layers"], cfg["hidden_size"]
@@ -21,6 +24,15 @@ def layout(cfg: Dict[str, Any]) -> Dict[str, Any]:
         "mlp_norm": {"scale": C.Leaf((n, d), "ones")},
     }
     return out
+
+
+def block_counts(cfg: Dict[str, Any], batch: int) -> C.BlockCounts:
+    """Attention and a SwiGLU MLP in every block, all weights used."""
+    L = cfg["num_hidden_layers"]
+    read, used, cache, flops = C.gqa_block_counts(cfg)
+    mlp = 3 * cfg["hidden_size"] * cfg["intermediate_size"]
+    return C.BlockCounts(L * (read + mlp), L * (used + mlp), L * cache,
+                         L * flops)
 
 
 def forward(w, tokens: jax.Array, rows: jax.Array, cfg: Dict[str, Any],

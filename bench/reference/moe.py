@@ -15,6 +15,12 @@ import jax.numpy as jnp
 
 from . import common as C
 
+MODEL_KEYS = {"moe_intermediate_size": "moe_d_ff",
+              "n_routed_experts": "n_experts",
+              "n_shared_experts": "n_shared_experts",
+              "num_experts_per_tok": "experts_per_token"}
+TEST_CUT = {"n_layers": 2, "n_experts": 16}
+
 
 def layout(cfg: Dict[str, Any]) -> Dict[str, Any]:
     if cfg["first_k_dense_replace"] or cfg["moe_layer_freq"] != 1:
@@ -34,6 +40,24 @@ def layout(cfg: Dict[str, Any]) -> Dict[str, Any]:
         },
     }
     return out
+
+
+def block_counts(cfg: Dict[str, Any], batch: int) -> C.BlockCounts:
+    """Attention and a mixture of experts in every block.  The routed
+    experts' weights are read for the expected number of distinct experts
+    that ``batch`` tokens hit under uniform routing,
+    E(1 - (1 - k/E)^batch), and used for k a token; the router and the
+    shared experts count whole."""
+    L, d = cfg["num_hidden_layers"], cfg["hidden_size"]
+    read, used, cache, flops = C.gqa_block_counts(cfg)
+    E, k = cfg["n_routed_experts"], cfg["num_experts_per_tok"]
+    expert = 3 * d * cfg["moe_intermediate_size"]
+    shared = expert * cfg["n_shared_experts"]
+    hit = E * (1.0 - (1.0 - k / E) ** batch)
+    mlp_read = d * E + shared + hit * expert
+    mlp_used = d * E + shared + k * expert
+    return C.BlockCounts(L * (read + mlp_read), L * (used + mlp_used),
+                         L * cache, L * flops)
 
 
 def router_weights(h: jax.Array, router: jax.Array, cfg: Dict[str, Any]

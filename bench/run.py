@@ -38,7 +38,7 @@ os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
 os.environ["REPRO_PLANNER_WORKERS"] = "1"      # no planner worker processes
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # libtpu logs to /tmp else
 
-from bench import check, harness, trace  # noqa: E402
+from bench import check, harness, scopes, trace  # noqa: E402
 
 
 @dataclasses.dataclass
@@ -50,6 +50,10 @@ class Reading:
     window: harness.Window
     trace: Optional[trace.Summary]
     peak: Dict[str, float]
+    # leaf-op device seconds by scope path, one dict per execution of the
+    # decode step in the decode phase of the traced batch
+    # (``scopes.decode_scopes``); None without a trace
+    decode_scopes: Optional[List[Dict[str, float]]] = None
 
     @property
     def model(self) -> Dict[str, Any]:
@@ -118,6 +122,7 @@ def run(cell: harness.Cell, seed: int, seconds: float, traced: bool,
     and ``peak`` in place of the device's row of ``bench/peaks.json``."""
     import jax
     from repro.launch import use_compile_cache
+    from repro.obs.scopes import scope_map
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     peak = peak or peaks_of(devices[0].device_kind)
@@ -130,13 +135,19 @@ def run(cell: harness.Cell, seed: int, seconds: float, traced: bool,
         memory = harness.memory_peak_bytes()
         plan_s = server.plan_s
         setup_parts = dict(start=t_setup - T_START, **server.phases)
+        # which scope each instruction of the one-token step lies in
+        step_scopes = scope_map(server.decode.as_text()) if traced else None
         del server
         gc.collect()
-        summary = None
+        summary = by_scope = None
         if traced and len(window.batches) > harness.TRACED_BATCH:
-            summary = trace.reduce(trace.find_xplane(trace_dir),
-                                   f"jit_{harness.STEP_NAME}",
-                                   harness.WINDOW_SPAN)
+            xplane, program = (trace.find_xplane(trace_dir),
+                               f"jit_{harness.STEP_NAME}")
+            summary = trace.reduce(xplane, program, harness.WINDOW_SPAN)
+            b = window.batches[harness.TRACED_BATCH]
+            by_scope = scopes.decode_scopes(xplane, step_scopes, program,
+                                            harness.WINDOW_SPAN,
+                                            b.generated.shape[1] - 1)
     finally:
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -147,7 +158,7 @@ def run(cell: harness.Cell, seed: int, seconds: float, traced: bool,
     verdict = check.verdict(cell.model, gaps)
 
     reading = Reading(cell, window.start - T_START, plan_s, window, summary,
-                      peak)
+                      peak, by_scope)
     metrics = {}
     for m in metric_specs(cell.name, traced):
         value = read_metric(m["name"], reading)
@@ -163,6 +174,8 @@ def run(cell: harness.Cell, seed: int, seconds: float, traced: bool,
         device.update(busy_s=summary.busy_s, window_s=summary.window_s)
         out["breakdown"] = {"device_ops": [list(o) for o in summary.ops],
                             "idle_gaps": [list(g) for g in summary.idle]}
+    if by_scope:
+        out["decode_scope_ms"] = scopes.top_level_ms(by_scope)
     out["check"] = dict(verdict["numbers"],
                         max_gap_per_request=gaps.max(-1).tolist())
     out["setup_parts"] = setup_parts

@@ -5,7 +5,8 @@ program's contract: ``repro.obs.scopes.scope_map`` reads it from the
 compiled step's HLO text (``as_text()``; DESIGN_OBS.md).
 :func:`decode_scopes` sums a profiler trace's leaf-op device time by
 scope over each execution of the step in the decode phase of the traced
-batch, the executions ``run.Reading.decode_steps`` reads.
+batch, the executions ``run.Reading.decode_steps`` reads; ``bench/run.py``
+puts the result on ``Reading.decode_scopes`` for the metric readers.
 """
 from __future__ import annotations
 
@@ -55,14 +56,23 @@ def totals(per_step: List[Dict[str, float]]) -> Dict[str, float]:
     return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
-def step_ms(per_step: List[Dict[str, float]], scope: str) -> float:
+def step_ms(per_step: List[Dict[str, float]], scope: str
+            ) -> Optional[float]:
     """Median over the executions of the milliseconds spent in ``scope``
-    and its sub-scopes."""
+    and its sub-scopes; None where no execution ran an op in it."""
     import numpy as np
-    return 1e3 * float(np.median([
-        sum(v for k, v in step.items()
-            if k == scope or k.startswith(scope + "/"))
-        for step in per_step]))
+    inside = [[v for k, v in step.items()
+               if k == scope or k.startswith(scope + "/")]
+              for step in per_step]
+    if not any(inside):
+        return None
+    return 1e3 * float(np.median([sum(v) for v in inside]))
+
+
+def top_level_ms(per_step: List[Dict[str, float]]) -> Dict[str, float]:
+    """``step_ms`` of each top-level scope, and of ``UNSCOPED``."""
+    tops = sorted({k.split("/")[0] for step in per_step for k in step})
+    return {t: step_ms(per_step, t) for t in tops}
 
 
 def unscoped_share(per_step: List[Dict[str, float]]) -> float:

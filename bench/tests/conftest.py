@@ -13,13 +13,12 @@ os.environ.setdefault("REPRO_PLANNER_WORKERS", "1")
 
 import pytest  # noqa: E402
 
-from bench import harness  # noqa: E402
+from bench import harness, reference  # noqa: E402
 
-# (configuration, traffic) of the benchmark's cells, one per family the
-# references cover
-FAMILIES = [("qwen2.5-3b", "chat-b16-ctx1k"),
-            ("deepseek-moe-16b-8L", "chat-b8-ctx1k")]
-CELLS = [f"{c}.{t}" for c, t in FAMILIES]
+# the first cell of each configuration in BENCHMARK.json
+_WORKLOADS = harness.load_json(ROOT / "BENCHMARK.json")["workloads"]
+CELLS = [w["name"] for i, w in enumerate(_WORKLOADS)
+         if w["config"] not in {v["config"] for v in _WORKLOADS[:i]}]
 SMALL_TRAFFIC = {"batch": 4, "slab": 48, "output_tokens": 8,
                  "prompt_len": {"dist": "lognormal", "median": 12,
                                 "sigma": 0.5, "scale": 1.0,
@@ -29,21 +28,14 @@ SMALL_TRAFFIC = {"batch": 4, "slab": 48, "output_tokens": 8,
 CPU_PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 
 
-def small_cell(workload: str, traffic=None, **sizes):
-    """(cell, ModelConfig) of ``workload`` (``<config>.<traffic>``) at the
-    reduced size, with ``sizes`` over the reduced config and ``traffic``
-    over the mix."""
-    config, mix = next((c, t) for c, t in FAMILIES
-                       if workload == f"{c}.{t}")
-    cell = harness.Cell(workload, 1,
-                        harness.load_json(harness.BENCH / "configs"
-                                          / f"{config}.json"),
-                        harness.load_json(harness.BENCH / "traffic"
-                                          / f"{mix}.json"))
-    cfg = harness.model_config(cell.model).reduced(**sizes)
-    keys = {**harness.MODEL_KEYS["common"],
-            **harness.MODEL_KEYS[cell.model["family"]]}
-    model = dict(cell.model, **{k: getattr(cfg, f) for k, f in keys.items()})
+def small_cell(workload: str, traffic=None, cfg=None, **sizes):
+    """(cell, ModelConfig) of ``workload`` at the reduced size, with
+    ``sizes`` over the reduced config, or at ``cfg``; ``traffic`` over the
+    mix."""
+    cell = harness.load_cell(workload)
+    cfg = cfg or harness.model_config(cell.model).reduced(**sizes)
+    model = dict(cell.model, **{k: getattr(cfg, f) for k, f
+                                in harness.model_keys(cell.model).items()})
     traffic = {**cell.traffic, **SMALL_TRAFFIC, **(traffic or {})}
     return dataclasses.replace(cell, model=model, traffic=traffic), cfg
 
@@ -52,3 +44,15 @@ def small_cell(workload: str, traffic=None, **sizes):
 def small(request):
     """Each of the benchmark's cells at the reduced size."""
     return small_cell(request.param)
+
+
+@pytest.fixture
+def family_dir(tmp_path, monkeypatch):
+    """A directory searched for family modules after ``bench/reference``;
+    the modules imported from it are forgotten after the test."""
+    monkeypatch.setattr(reference, "__path__",
+                        [*reference.__path__, str(tmp_path)])
+    yield tmp_path
+    for name, mod in list(sys.modules.items()):
+        if str(getattr(mod, "__file__", None)).startswith(str(tmp_path)):
+            del sys.modules[name]

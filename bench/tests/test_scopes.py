@@ -1,7 +1,7 @@
 """``bench/scopes.py`` on a small CPU trace of the serving loop
-(``record_serve_trace.py`` says what it holds), and the reader of what
-the program records and ``bench/probe.py``, in a traced run at the
-reduced size."""
+(``record_serve_trace.py`` says what it holds), and the readers of what
+the program records, the scope metrics among them, in a traced run at
+the reduced size."""
 import json
 from pathlib import Path
 
@@ -10,7 +10,7 @@ import pytest
 
 from bench import harness, run, scopes, trace
 
-from conftest import CPU_PEAK
+from conftest import CELLS, CPU_PEAK, small_cell
 
 DATA = Path(__file__).resolve().parent / "data"
 PATH = str(DATA / "cpu_serve_trace.xplane.pb")
@@ -75,30 +75,28 @@ def test_idle_gaps_in_the_loop_carry_serve_spans():
     assert "bench.greedy_generate" not in idle
 
 
-def test_new_readers_read_a_traced_run(small):
-    cell, cfg = small
-    out = run.run(cell, SEED, 0.2, True, jax.devices(), cfg=cfg,
-                  peak=CPU_PEAK)
+@pytest.fixture(scope="module", params=CELLS)
+def traced_run(request):
+    cell, cfg = small_cell(request.param)
+    return run.run(cell, SEED, 0.2, True, jax.devices(), cfg=cfg,
+                   peak=CPU_PEAK)
+
+
+def test_new_readers_read_a_traced_run(traced_run):
+    out = traced_run
     assert out["metrics"]["window_compiles"]["value"] == 0
     assert any(n.startswith("serve.")
                for n, _ in out["breakdown"]["idle_gaps"])
 
 
-def test_probe_adds_scopes_to_a_traced_run(small):
-    """``bench/probe.py`` is ``run.run`` with its readings added: the
-    run's own keys, and the decode phase's device time by scope."""
-    from bench import probe
-    cell, cfg = small
-    out = probe.probe(cell, SEED + 1, 0.2, True, jax.devices(), cfg=cfg,
-                      peak=CPU_PEAK)
-    assert out["correct"] and "step_device_ms" in out["metrics"]
-    extra = out["probe"]
-    assert not extra["spans_on"]
-    assert extra["batch_compiles"] == [0] * len(out["batches"])
-    got = extra["scopes"]
+def test_traced_run_reports_scope_metrics(traced_run):
+    """``bench/run.py`` reduces the decode phase by scope
+    (``Reading.decode_scopes``) and its readers report each scope."""
+    out = traced_run
+    assert out["correct"]
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    for name in ("attention_step_ms", "ffn_step_ms", "layer_loop_step_ms"):
+        assert got[name] > 0
     assert got["unscoped_share"] == 0.0
-    for scope in ("attention", "attention/core", "ffn", "layer_loop",
-                  "lm_head"):
-        assert got["step_ms"][scope] > 0
-    assert got["scoped_plus_unscoped_ms"] > 0
-    assert set(got["device_scopes_s"]) >= {"attention/core", "ffn"}
+    assert {"attention", "ffn", "layer_loop",
+            "lm_head"} <= set(out["decode_scope_ms"])
