@@ -8,7 +8,8 @@ from typing import Optional, Tuple
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                      # dense | ssm | hybrid | moe | vlm | audio
+    family: str                      # dense | ssm | hybrid | moe | mla | vlm
+                                     # | audio
     n_layers: int
     d_model: int
     n_heads: int
@@ -29,6 +30,18 @@ class ModelConfig:
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    router_scoring: str = "softmax"  # softmax | sigmoid (selection biased
+                                     # by a per-expert correction, noaux_tc)
+    routed_scaling: float = 1.0      # sigmoid: the gates' scale
+    n_experts_held: int = 0          # experts on this device (0 -> all):
+                                     # the router still scores n_experts
+    n_dense_layers: int = 0          # leading blocks with a dense MLP of
+                                     # width d_ff before the MoE blocks
+    # -- multi-head latent attention (0 -> none) ------------------------------
+    kv_lora_rank: int = 0            # the cached latent's width
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0        # the decoupled RoPE key, shared by heads
+    v_head_dim: int = 0
     # -- SSM / hybrid --------------------------------------------------------
     ssm_state: int = 0
     ssm_heads: int = 0               # mamba2 heads (0 -> derived)
@@ -62,14 +75,20 @@ class ModelConfig:
         return self.head_dim or (self.d_model // self.n_heads)
 
     @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    @property
     def q_per_kv(self) -> int:
         return self.n_heads // max(1, self.n_kv_heads)
 
     def reduced(self, **overrides) -> "ModelConfig":
         """A small same-family config for CPU smoke tests."""
+        dense = min(self.n_dense_layers, 1)
         small = dict(
-            n_layers=min(self.n_layers, 2 if self.attn_every == 0
-                         else 2 * self.attn_every),
+            n_layers=min(self.n_layers, dense + (2 if self.attn_every == 0
+                                                 else 2 * self.attn_every)),
+            n_dense_layers=dense,
             d_model=128,
             n_heads=4,
             n_kv_heads=max(1, min(self.n_kv_heads,
@@ -83,6 +102,11 @@ class ModelConfig:
             experts_per_token=min(self.experts_per_token, 2)
             if self.experts_per_token else 0,
             n_shared_experts=min(self.n_shared_experts, 1),
+            n_experts_held=min(self.n_experts_held, 4),
+            kv_lora_rank=min(self.kv_lora_rank, 32),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, 16),
+            qk_rope_head_dim=min(self.qk_rope_head_dim, 8),
+            v_head_dim=min(self.v_head_dim, 16),
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             n_encoder_layers=min(self.n_encoder_layers, 2),
             frontend_dim=min(self.frontend_dim, 64) if self.frontend_dim else 0,

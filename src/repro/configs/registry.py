@@ -7,14 +7,15 @@ from typing import Dict, Iterator, Optional, Tuple
 from .base import ModelConfig, ShapeConfig
 from .shapes import SHAPES
 from . import (deepseek_67b, deepseek_moe_16b, gemma_7b, internvl2_1b,
-               llama3_405b, qwen2_5_3b, qwen3_moe_30b_a3b, rwkv6_3b,
-               seamless_m4t_medium, zamba2_1p2b)
+               llama3_405b, moonlight_16b_a3b, qwen2_5_3b,
+               qwen3_moe_30b_a3b, rwkv6_3b, seamless_m4t_medium,
+               zamba2_1p2b)
 
 ARCHS: Dict[str, ModelConfig] = {
     m.CONFIG.name: m.CONFIG
     for m in (gemma_7b, qwen2_5_3b, llama3_405b, deepseek_67b, rwkv6_3b,
               zamba2_1p2b, internvl2_1b, qwen3_moe_30b_a3b, deepseek_moe_16b,
-              seamless_m4t_medium)
+              seamless_m4t_medium, moonlight_16b_a3b)
 }
 
 

@@ -11,8 +11,9 @@ compilation) is reported apart from prefill and decode time.
 One jitted serve step does both phases.  The prompt goes through it in
 chunks whose widths come from the fixed power-of-two ladder
 :data:`PREFILL_WIDTHS` (a prompt of 227 tokens in five executions,
-128 + 64 + 32 + 2 + 1), where the cache is a KV slab alone; a cache that
-holds recurrent state takes the prompt one token a step.  Decoding feeds
+128 + 64 + 32 + 2 + 1), where the cache is position slabs alone (a KV
+or a latent cache); a cache that holds recurrent state takes the prompt
+one token a step.  Decoding feeds
 one token a step.
 
 Serving-layer observability (DESIGN_OBS.md): ``--introspect-port`` starts
@@ -244,15 +245,28 @@ def _pick(logits: jax.Array, vocab_size: int):
 PREFILL_WIDTHS = (128, 64, 32, 16, 8, 4, 2, 1)
 
 
+# Cache leaves that hold one entry a position along axis 2: a KV cache's
+# keys and values, a latent cache's ``c_kv`` and ``k_pe``.
+POSITION_SLABS = frozenset({"k", "v", "c_kv", "k_pe"})
+
+
 def chunk_widths(cache) -> Tuple[int, ...]:
     """The widths of :data:`PREFILL_WIDTHS` that a serve step over
-    ``cache`` can take: those that fit its slab where the cache is a KV
-    slab alone (keys ``k``, ``v``, ``index``); else one token, since a
-    chunk cannot advance recurrent state."""
-    if set(cache) != {"k", "v", "index"}:
+    ``cache`` can take: those that fit its slab where every leaf but
+    ``index`` is a position slab (:data:`POSITION_SLABS`); else one token,
+    since a chunk cannot advance recurrent state."""
+    slabs = set(cache) - {"index"}
+    if "index" not in cache or not slabs or not slabs <= POSITION_SLABS:
         return (1,)
-    slab = cache["k"].shape[2]
+    slab = min(cache[k].shape[2] for k in slabs)
     return tuple(w for w in PREFILL_WIDTHS if w <= slab)
+
+
+def cache_layout(cache) -> str:
+    """``latent``, ``kv`` or ``state``: what a position of ``cache``
+    holds, the label of ``serve_cache_bytes``."""
+    return ("latent" if "c_kv" in cache else "kv" if "k" in cache
+            else "state")
 
 
 def prompt_chunks(n: int, widths) -> List[Tuple[int, int]]:
@@ -328,7 +342,8 @@ def greedy_generate(decode, pick, params, prompts, cache,
     (:func:`prompt_chunks` over ``decode.widths``), then greedy-decode
     ``n_tokens`` ids one step each; ``decode``/``pick`` come from
     :func:`compile_greedy`.  Counts each chunk in
-    ``serve_prefill_chunks_total{width}``.
+    ``serve_prefill_chunks_total{width}``, and sets
+    ``serve_cache_bytes{layout}`` to the bytes of ``cache``.
 
     Spans (category ``serve``, DESIGN_OBS.md): ``serve.prefill`` and
     ``serve.decode`` cover the two phases, ``serve.step`` each feed and
@@ -337,6 +352,9 @@ def greedy_generate(decode, pick, params, prompts, cache,
     are recorded while tracing is on, and reach a running JAX profiler
     capture either way."""
     prompts = np.asarray(prompts)
+    metrics.set_gauge("serve_cache_bytes",
+                      sum(a.nbytes for a in jax.tree.leaves(cache)),
+                      layout=cache_layout(cache))
     span = _loop_spans()
     t0 = time.perf_counter()
     with span("serve.prefill"):

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
-from . import encdec, moe, param as P, rwkv6, transformer, vlm, zamba2
+from . import encdec, mla, moe, param as P, rwkv6, transformer, vlm, zamba2
 
 Params = Dict[str, Any]
 
@@ -120,6 +120,14 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
             decode_step=lambda p, t, c: moe.decode_step(p, t, c, cfg),
             init_cache=moe.init_cache,
             cache_axes=moe.cache_logical_axes)
+    if fam == "mla":
+        return ModelAPI(
+            cfg=cfg, spec=_cast(mla.mla_spec(cfg), cfg),
+            loss_fn=lambda p, b: mla.loss_fn(p, b, cfg),
+            logits_fn=lambda p, b: mla.forward(p, b["tokens"], cfg)[0],
+            decode_step=lambda p, t, c: mla.decode_step(p, t, c, cfg),
+            init_cache=mla.init_cache,
+            cache_axes=mla.cache_logical_axes)
     if fam == "ssm":
         return ModelAPI(
             cfg=cfg, spec=_cast(rwkv6.rwkv6_spec(cfg), cfg),

@@ -42,6 +42,20 @@ def rmsnorm(p: Params, x: jax.Array, eps: float) -> jax.Array:
     return (out * p["scale"].astype(jnp.float32)).astype(dt)
 
 
+def einsum_f32(spec: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    """``einsum(spec, a, b)`` with a float32 result and accumulation,
+    reading ``a`` and ``b`` in their own dtype: no float32 copy of a
+    bfloat16 operand.  XLA's CPU backend has no bfloat16 dot with a
+    float32 result, so there the operands are widened first (the same
+    products: a bfloat16 product is exact in float32)."""
+    f32 = jnp.float32
+    return jax.lax.platform_dependent(
+        a, b,
+        cpu=lambda a, b: jnp.einsum(spec, a.astype(f32), b.astype(f32)),
+        default=lambda a, b: jnp.einsum(spec, a, b,
+                                        preferred_element_type=f32))
+
+
 # ------------------------------------------------------------------- RoPE
 def rope_frequencies(head_dim: int, theta: float, positions: jax.Array
                      ) -> Tuple[jax.Array, jax.Array]:
@@ -129,7 +143,7 @@ def _sdpa_xla_chunked(q, k, v, causal: bool, sm_scale: float,
     qf = q.astype(jnp.float32)
     m0 = jnp.full((B, S, H), -1e30, jnp.float32)
     l0 = jnp.zeros((B, S, H), jnp.float32)
-    a0 = jnp.zeros((B, S, H, D), jnp.float32)
+    a0 = jnp.zeros((B, S, H, v.shape[-1]), jnp.float32)
     qpos = jnp.arange(S)[:, None] + (T - S)
 
     def kv_step(carry, kj):

@@ -1,9 +1,11 @@
-"""Mixture-of-Experts transformer (qwen3-moe-30b-a3b, deepseek-moe-16b).
+"""Mixture-of-Experts transformer (qwen3-moe-30b-a3b, deepseek-moe-16b; the
+expert layer of moonlight-16b-a3b, ``models/mla.py``).
 
 Sort-based capacity dispatch (O(T*k) memory — no T x E x cap one-hots, so the
 32k-prefill dry-run fits):
 
-1. router softmax -> top-k experts/weights per token;
+1. router (softmax, or sigmoid with a selection bias) -> top-k
+   experts/weights per token over all ``n_experts``;
 2. flatten (token, slot) pairs, sort by expert id;
 3. rank-in-expert via sorted-position minus group offset; drop beyond
    capacity;
@@ -14,6 +16,11 @@ Sort-based capacity dispatch (O(T*k) memory — no T x E x cap one-hots, so the
 DeepSeekMoE details honoured: ``n_shared_experts`` dense experts always on
 (fine-grained experts with small ``moe_d_ff``), plus the standard
 load-balancing auxiliary loss.
+
+A device may hold ``n_experts_held`` of the experts (its share under
+expert parallelism): the router keeps its full width, and the layer gives
+the part of its output that the held experts compute, without the
+exchange that would add the other devices' parts.
 """
 from __future__ import annotations
 
@@ -32,13 +39,18 @@ Params = Dict[str, Any]
 
 
 def moe_mlp_spec(cfg: ModelConfig) -> Params:
-    d, f, E = cfg.d_model, cfg.moe_d_ff, cfg.n_experts
+    """The router scores all ``n_experts``; the expert leaves hold the
+    ``experts_held`` of them that live on this device."""
+    d, f, E, n = cfg.d_model, cfg.moe_d_ff, cfg.n_experts, cfg.experts_held
     spec: Params = {
         "router": LeafSpec((d, E), ("embed", "experts")),
-        "w_gate": LeafSpec((E, d, f), ("experts", "embed", "ffn")),
-        "w_up": LeafSpec((E, d, f), ("experts", "embed", "ffn")),
-        "w_down": LeafSpec((E, f, d), ("experts", "ffn", "embed")),
+        "w_gate": LeafSpec((n, d, f), ("experts", "embed", "ffn")),
+        "w_up": LeafSpec((n, d, f), ("experts", "embed", "ffn")),
+        "w_down": LeafSpec((n, f, d), ("experts", "ffn", "embed")),
     }
+    if cfg.router_scoring == "sigmoid":
+        spec["e_score_correction_bias"] = LeafSpec((E,), ("experts",),
+                                                   init="zeros")
     if cfg.n_shared_experts:
         spec["shared"] = L.mlp_spec(cfg, d_ff=cfg.moe_d_ff * cfg.n_shared_experts)
     return spec
@@ -109,8 +121,18 @@ def _dispatch_ffn_combine(xf: jax.Array, p_gate, p_up, p_down,
         return jnp.zeros((T, d), xf.dtype).at[st].add(gathered)
 
 
-def _router(xf: jax.Array, router_w: jax.Array, cfg: ModelConfig):
-    logits = jnp.einsum("td,de->te", xf, router_w.astype(xf.dtype))
+def _router(xf: jax.Array, p: Params, cfg: ModelConfig):
+    """-> (gates, expert ids, aux loss), each token's ``experts_per_token``
+    choices among all ``n_experts``.
+
+    ``softmax``: the largest probabilities, renormalised, with a
+    Switch-style load-balancing loss.
+    ``sigmoid`` (DeepSeek-V3's ``noaux_tc`` with one group): chosen by
+    score plus ``e_score_correction_bias``; the gates are the unbiased
+    scores of the chosen, renormalised, times ``routed_scaling``.  The bias balances load in place of a loss."""
+    if cfg.router_scoring == "sigmoid":
+        return _sigmoid_router(xf, p, cfg)
+    logits = jnp.einsum("td,de->te", xf, p["router"].astype(xf.dtype))
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gate_vals, expert_idx = jax.lax.top_k(probs, cfg.experts_per_token)
     gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
@@ -121,6 +143,18 @@ def _router(xf: jax.Array, router_w: jax.Array, cfg: ModelConfig):
                           axis=1), axis=0)
     aux = E * jnp.sum(me * ce) * cfg.router_aux_weight
     return gate_vals, expert_idx, aux
+
+
+def _sigmoid_router(xf: jax.Array, p: Params, cfg: ModelConfig):
+    scores = jax.nn.sigmoid(L.einsum_f32("td,de->te", xf,
+                                         p["router"].astype(xf.dtype)))
+    biased = scores + p["e_score_correction_bias"].astype(jnp.float32)
+    _, expert_idx = jax.lax.top_k(biased, cfg.experts_per_token)
+    gate_vals = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    gate_vals = gate_vals / (jnp.sum(gate_vals, axis=-1, keepdims=True)
+                             + 1e-20)
+    gate_vals = gate_vals * cfg.routed_scaling
+    return gate_vals, expert_idx, jnp.zeros((), jnp.float32)
 
 
 def _ep_axes() -> Tuple[Optional[Tuple[str, ...]], Optional[str]]:
@@ -165,18 +199,20 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig,
     B, S, d = x.shape
     b_axes, e_ax = _ep_axes()
     mesh = SH._CTX.mesh
-    if e_ax is not None and cfg.n_experts % mesh.shape[e_ax] == 0 \
+    router = {k: p[k] for k in ("router", "e_score_correction_bias")
+              if k in p}
+    if e_ax is not None and cfg.experts_held % mesh.shape[e_ax] == 0 \
             and B % max(1, math.prod(mesh.shape[a] for a in b_axes)) == 0:
         ep = mesh.shape[e_ax]
-        n_local = cfg.n_experts // ep
+        n_local = cfg.experts_held // ep
         bspec = tuple(b_axes) if len(b_axes) > 1 else (
             b_axes[0] if b_axes else None)
 
-        def local_moe(xl, router_w, wg, wu, wd):
+        def local_moe(xl, router_p, wg, wu, wd):
             Bl, Sl, _ = xl.shape
             xf = xl.reshape(Bl * Sl, d)
             with jax.named_scope("router"):
-                gate_vals, expert_idx, aux = _router(xf, router_w, cfg)
+                gate_vals, expert_idx, aux = _router(xf, router_p, cfg)
             e_lo = jax.lax.axis_index(e_ax) * n_local
             cap = _capacity(Bl * Sl, cfg, drop_free)
             yf = _dispatch_ffn_combine(xf, wg, wu, wd, gate_vals,
@@ -190,19 +226,21 @@ def moe_mlp(p: Params, x: jax.Array, cfg: ModelConfig,
 
         y, aux = shard_map(
             local_moe, mesh=mesh,
-            in_specs=(P(bspec, None, None), P(None, None),
+            in_specs=(P(bspec, None, None), P(),
                       P(e_ax, None, None), P(e_ax, None, None),
                       P(e_ax, None, None)),
             out_specs=(P(bspec, None, None), P()),
-        )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
+        )(x, router, p["w_gate"], p["w_up"], p["w_down"])
     else:
         xf = x.reshape(B * S, d)
         with jax.named_scope("router"):
-            gate_vals, expert_idx, aux = _router(xf, p["router"], cfg)
+            gate_vals, expert_idx, aux = _router(xf, router, cfg)
         cap = _capacity(B * S, cfg, drop_free)
+        # the experts held here are the first ``experts_held``: the share
+        # of the layer's output that their tokens give
         yf = _dispatch_ffn_combine(xf, p["w_gate"], p["w_up"], p["w_down"],
                                    gate_vals, expert_idx, cfg, 0,
-                                   cfg.n_experts, cap)
+                                   cfg.experts_held, cap)
         y = yf.reshape(B, S, d)
 
     if "shared" in p:

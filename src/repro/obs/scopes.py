@@ -20,10 +20,21 @@ layout copy, an upcast ahead of a dot), so it takes the scope of its
 first user that does device work, passing through tuples,
 get-tuple-elements and bitcasts, up to ``INHERIT_STEPS`` deep; failing
 that (a copy into the step's result), that of its first operand that
-has one, searched the same way.  So the float32 copies of a layer's whole
-K and V slab, which XLA adds for the scores and p.v dots, lie in
-``attention/core`` as the contract says, not in the projection or cache
-write that produced them.
+has one, searched the same way; failing that (a prefetch of a weight for
+the step's next execution, which XLA adds at the step's end and nothing
+uses), that of another user of an operand up to ``INHERIT_STEPS`` above
+it.  So the float32 copies of a layer's whole K and V slab, which XLA
+adds for the scores and p.v dots, lie in ``attention/core`` as the
+contract says, not in the projection or cache write that produced them.
+
+Latent attention (``models/mla.py``) keeps the contract: ``proj`` holds
+every weight product (the query, the latent and rope key, the absorption
+of ``W_UK`` into the query and of ``W_UV`` into the output, the output
+projection) with the latent's norm and RoPE; ``kv_cache`` the new
+positions' latents as the cache stores them and their one write into the
+cache after the layer loop (with the position count); ``core`` only what
+reads the latent slab: scores, softmax and p.c_kv.  The sigmoid router's
+selection lies in ``ffn/router``.
 
 :func:`scope_map` reads a compiled step's HLO text (``as_text()``);
 :func:`uncovered` lists the device ops it leaves in no scope.
@@ -143,12 +154,22 @@ def scope_map(hlo_text: str) -> Dict[str, str]:
                 return s
         return None
 
+    def ancestors(name: str, steps: int):
+        for o in instrs[name][2]:
+            if o in instrs:
+                yield o
+                if steps > 1:
+                    yield from ancestors(o, steps - 1)
+
     def resolve(name: str) -> Optional[str]:
         op_name = instrs[name][1]
         if op_name is not None:
             return scope_of(op_name)
         return (search(name, INHERIT_STEPS, users.__getitem__, NO_WORK)
-                or search(name, INHERIT_STEPS, lambda n: instrs[n][2], ()))
+                or search(name, INHERIT_STEPS, lambda n: instrs[n][2], ())
+                or next(filter(None, (
+                    search(a, INHERIT_STEPS, users.__getitem__, NO_WORK)
+                    for a in ancestors(name, INHERIT_STEPS))), None))
 
     return {n: resolve(n) or UNSCOPED for n in instrs}
 

@@ -143,6 +143,8 @@ ENTRY %main (a: f32[4]) -> f32[4] {
   %copy.2 = f32[4] copy(%a)
   %neg.1 = f32[4] negate(%copy.2), metadata={op_name="jit(f)/embed/neg"}
   %while.1 = (s32[], f32[4]) while(%t), condition=%cond, body=%body, metadata={op_name="jit(f)/layer_loop/while"}
+  %copy-start.1 = (f32[4], f32[4], u32[]) copy-start(%a)
+  %copy-done.1 = f32[4] copy-done(%copy-start.1)
   ROOT %copy.3 = f32[4] copy(%neg.1)
 }
 """
@@ -157,6 +159,8 @@ ENTRY %main (a: f32[4]) -> f32[4] {
                                      # constant's)
     ("fusion.1", "ffn"),             # its own op_name first
     ("while.1", "layer_loop"),
+    ("copy-start.1", "embed"),       # a prefetch nothing uses: the
+    ("copy-done.1", "embed"),        # parameter's other user's
 ])
 def test_compiler_added_ops_inherit_a_scope(instr, scope):
     assert scopes.scope_map(_HLO)[instr] == scope

@@ -161,3 +161,58 @@ def test_qwen_decode_step_ops_all_have_scopes(one_chip):
         r"^\s*%([\w.\-]+) = f32\[16,1024,2,128\]", text, re.M)
         if n in found]
     assert slab_f32 and {found[n] for n in slab_f32} == {"attention/core"}
+
+
+@pytest.mark.parametrize("width", [1, 128])
+def test_latent_serve_step_fits_and_keeps_scopes(one_chip, width):
+    """moonlight-16b-a3b's serve step as the benchmark runs it (full width,
+    8 of 64 experts held, batch 16, slab 8192) compiles for one chip and
+    fits its HBM.  Decoding, it reads the latent slab where it lies: no
+    temporary holds a layer's slab (16 x 8192 x 512 bfloat16, 134 MB), and
+    every device op lies in a named scope."""
+    import dataclasses
+    from repro.launch.serve import serving_config
+    from repro.models import build_model
+    from repro.obs import scopes
+    cfg = dataclasses.replace(serving_config("moonlight-16b-a3b"),
+                              n_experts_held=8)
+    api = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    params = on_chip(api.abstract_params())
+    cache = on_chip(jax.eval_shape(lambda: api.init_cache(cfg, 16, 8192)))
+    tokens = jax.ShapeDtypeStruct((16, width), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(api.decode_step, donate_argnums=(2,)).lower(
+        params, tokens, cache).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < V5E_USABLE_HBM)
+    assert mem.alias_size_in_bytes > 4e9             # the cache, in place
+    if width == 1:
+        assert mem.temp_size_in_bytes < 16 * 8192 * 512 * 2
+        text = compiled.as_text()
+        assert scopes.uncovered(text) == []
+        scope = scopes.scope_map(text)
+        assert {"layer_loop", "attention/proj", "attention/kv_cache",
+                "attention/core", "ffn/router", "ffn/experts",
+                "lm_head"} <= set(scope.values())
+        # the trailing prefetch of the next execution's first weight (a
+        # copy-done nothing uses) takes the scope of that weight's other
+        # user, the dense MLP's ("ffn"), and adds nothing to the core's
+        instrs = scopes.instructions(text)
+        users = {}
+        for n, (_, _, operands) in instrs.items():
+            for o in operands:
+                users.setdefault(o, []).append(n)
+        trailing = [n for n, (op, _, _) in instrs.items()
+                    if op == "copy-done" and n not in users]
+        assert trailing
+        for n in trailing:
+            (start,) = instrs[n][2]
+            (weight,) = instrs[start][2]
+            others = {scope[u] for u in users[weight] if u != start}
+            assert scope[n] in others
+            assert scope[n] != "attention/core"
