@@ -40,7 +40,7 @@ from repro.obs import expo, flightrec, metrics, slo, trace
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.data import DataConfig, make_source
 from repro.launch import use_compile_cache
-from repro.models import build_model
+from repro.models import build_model, mla
 from repro.planservice import PlanService
 
 
@@ -341,9 +341,12 @@ def greedy_generate(decode, pick, params, prompts, cache,
     """Feed the prompt (host ids, B x P) through ``decode`` in chunks
     (:func:`prompt_chunks` over ``decode.widths``), then greedy-decode
     ``n_tokens`` ids one step each; ``decode``/``pick`` come from
-    :func:`compile_greedy`.  Counts each chunk in
-    ``serve_prefill_chunks_total{width}``, and sets
-    ``serve_cache_bytes{layout}`` to the bytes of ``cache``.
+    :func:`compile_greedy`; ``cache`` holds no position yet.  Counts each
+    chunk in ``serve_prefill_chunks_total{width}``, sets
+    ``serve_cache_bytes{layout}`` to the bytes of ``cache``, and, for a
+    latent cache, adds each step's slab positions that latent attention's
+    core reads and the slab's length to
+    ``serve_latent_positions_total{part="read"|"slab"}``.
 
     Spans (category ``serve``, DESIGN_OBS.md): ``serve.prefill`` and
     ``serve.decode`` cover the two phases, ``serve.step`` each feed and
@@ -355,6 +358,16 @@ def greedy_generate(decode, pick, params, prompts, cache,
     metrics.set_gauge("serve_cache_bytes",
                       sum(a.nbytes for a in jax.tree.leaves(cache)),
                       layout=cache_layout(cache))
+    slab = cache["c_kv"].shape[2] if cache_layout(cache) == "latent" \
+        else None
+
+    def count_read(index: int) -> None:
+        if slab is not None:
+            width, blocks = mla.slab_blocks(index, slab)
+            metrics.inc("serve_latent_positions_total", width * blocks,
+                        part="read")
+            metrics.inc("serve_latent_positions_total", slab, part="slab")
+
     span = _loop_spans()
     t0 = time.perf_counter()
     with span("serve.prefill"):
@@ -362,6 +375,7 @@ def greedy_generate(decode, pick, params, prompts, cache,
             with span("serve.step"):
                 logits, cache = decode(params, prompts[:, t:t + w], cache)
             metrics.inc("serve_prefill_chunks_total", width=w)
+            count_read(t)
         row, tok = pick(logits)
         with span("serve.sync"):
             jax.block_until_ready(tok)
@@ -370,12 +384,13 @@ def greedy_generate(decode, pick, params, prompts, cache,
     rows, out = [row], [tok]
     t0 = time.perf_counter()
     with span("serve.decode"):
-        for _ in range(n_tokens - 1):
+        for i in range(n_tokens - 1):
             with span("serve.step"):
                 logits, cache = decode(params, tok, cache)
                 row, tok = pick(logits)
                 rows.append(row)
                 out.append(tok)
+            count_read(prompts.shape[1] + i)
         with span("serve.sync"):
             jax.block_until_ready(tok)
     decode_s = time.perf_counter() - t0

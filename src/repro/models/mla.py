@@ -44,6 +44,12 @@ from .param import LeafSpec, stack_specs
 
 Params = Dict[str, Any]
 LATENT_NORM_EPS = 1e-6
+# slab positions the absorbed core reads at a time; it visits only the
+# blocks that hold a filled position (:func:`slab_blocks`)
+BLOCK = 512
+# the running max before any position: finite, so that a step with no
+# filled position (a prompt's first chunk) gives no NaN
+NEG_INF = -1e30
 
 
 # ------------------------------------------------------------------- spec
@@ -128,16 +134,28 @@ def attention(p: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
         return _out(p, o)
 
 
+def slab_blocks(index, slab: int):
+    """(block width, blocks) of the absorbed core over a slab of ``slab``
+    positions that holds ``index``: blocks of :data:`BLOCK` positions (the
+    whole slab where that is shorter), those that start below ``index``.
+    ``index`` is the step's traced count or a host integer."""
+    width = min(BLOCK, slab)
+    return width, (index + width - 1) // width
+
+
 def cached_attention(p: Params, x: jax.Array, cfg: ModelConfig,
-                     c_kv: jax.Array, k_pe: jax.Array, index: jax.Array):
+                     cache: Dict[str, jax.Array], layer: jax.Array):
     """Absorbed attention of the S new positions ``x`` (B, S, d) at
-    ``index``, over this layer's latent slab ``c_kv`` (B, T, r) and
-    ``k_pe`` (B, T, rope) as it held ``index`` positions, and over the
-    new positions themselves, query i seeing positions up to
-    ``index + i``.  Returns (out, the new positions' c_kv and k_pe as the
-    cache stores them)."""
+    ``index = cache["index"]``, over layer ``layer``'s latent slab of the
+    latent cache (:func:`init_cache`) as it held ``index`` positions, and
+    over the new positions themselves, query i seeing positions up to
+    ``index + i``.  The core reads the slab a block at a time straight
+    from the cache, only the blocks below ``index``
+    (:func:`slab_blocks`).  Returns (out, the new positions' c_kv and k_pe
+    as the cache stores them)."""
     B, S, _ = x.shape
-    dt = x.dtype
+    dt, index = x.dtype, cache["index"]
+    c_kv, k_pe = cache["c_kv"], cache["k_pe"]
     with jax.named_scope("proj"):
         q_nope, q_pe, new_c, new_pe = _project(p, x, cfg,
                                                index + jnp.arange(S))
@@ -146,24 +164,42 @@ def cached_attention(p: Params, x: jax.Array, cfg: ModelConfig,
     with jax.named_scope("kv_cache"):
         new_c, new_pe = new_c.astype(c_kv.dtype), new_pe.astype(k_pe.dtype)
     with jax.named_scope("core"):
-        # one softmax over the slab's first ``index`` positions and the
-        # chunk's own, each read where it lies
-        parts = []
-        for ck, kp, seen in (
-                (c_kv, k_pe, jnp.arange(c_kv.shape[1])[None, :] < index),
-                (new_c, new_pe, jnp.tril(jnp.ones((S, S), bool)))):
+        # one online softmax over the slab's first ``index`` positions,
+        # block by block, and then the chunk's own, each read where it
+        # lies: no block past ``index`` is read
+        T, f32 = c_kv.shape[2], jnp.float32
+        width, n = slab_blocks(index, T)
+
+        def fold(state, ck, kp, seen):
+            m, denom, acc = state
             ck, kp = ck.astype(dt), kp.astype(dt)
             s = (L.einsum_f32("bshr,btr->bhst", q_lat, ck)
                  + L.einsum_f32("bshp,btp->bhst", q_pe, kp)) * _scale(cfg)
-            parts.append((jnp.where(seen[None, None], s, -jnp.inf), ck))
-        m = jnp.maximum(*[jnp.max(s, axis=-1, keepdims=True)
-                          for s, _ in parts])
-        o_lat, denom = 0.0, 0.0
-        for s, ck in parts:
-            e = jnp.exp(s - m)
-            denom = denom + jnp.sum(e, axis=-1)
-            o_lat = o_lat + L.einsum_f32("bhst,btr->bshr", e.astype(dt), ck)
-        o_lat = (o_lat / jnp.swapaxes(denom, 1, 2)[..., None]).astype(dt)
+            s = jnp.where(seen, s, NEG_INF)
+            top = jnp.maximum(m, jnp.max(s, axis=-1))
+            e, rescale = jnp.exp(s - top[..., None]), jnp.exp(m - top)
+            return (top, denom * rescale + jnp.sum(e, axis=-1),
+                    acc * rescale[..., None]
+                    + L.einsum_f32("bhst,btr->bhsr", e.astype(dt), ck))
+
+        def block(j, state):
+            # the last block of a slab that is no multiple of ``width``
+            # starts early; its positions before ``j * width`` are masked,
+            # as the block before counted them
+            start = jnp.minimum(j * width, T - width)
+            ck, kp = (jax.lax.dynamic_slice(
+                a, (layer, 0, start, 0), (1, B, width, a.shape[3]))[0]
+                for a in (c_kv, k_pe))
+            pos = start + jnp.arange(width)
+            return fold(state, ck, kp, (pos >= j * width) & (pos < index))
+
+        H, r = q_lat.shape[2], q_lat.shape[3]
+        state = (jnp.full((B, H, S), NEG_INF, f32), jnp.zeros((B, H, S), f32),
+                 jnp.zeros((B, H, S, r), f32))
+        state = jax.lax.fori_loop(0, n, block, state)
+        _, denom, acc = fold(state, new_c, new_pe,
+                             jnp.tril(jnp.ones((S, S), bool)))
+        o_lat = jnp.swapaxes(acc / denom[..., None], 1, 2).astype(dt)
     with jax.named_scope("proj"):
         # W_UV folded into the output
         o = jnp.einsum("bshr,hrv->bshv", o_lat, p["wv_b"].astype(dt))
@@ -251,7 +287,8 @@ def decode_step(params: Params, tokens: jax.Array,
     decodes, S > 1 feeds a prompt chunk (as ``transformer.decode_step``).
 
     The dense blocks run first, then the scan over the MoE blocks; each
-    reads its layer's latent slab and returns only its S new positions,
+    reads the blocks of its layer's latent slab that hold positions
+    (``cached_attention``) and returns only its S new positions,
     which one write puts into the donated cache after the loop: no
     layer's slab is restacked.  No token is dropped by expert capacity."""
     with jax.named_scope("embed"):
@@ -262,10 +299,8 @@ def decode_step(params: Params, tokens: jax.Array,
         p, layer = xs
         with jax.named_scope("attention"):
             a = L.rmsnorm(p["attn_norm"], h, cfg.norm_eps)
-            c_kv, k_pe = (jax.lax.dynamic_index_in_dim(
-                cache[k], layer, 0, keepdims=False) for k in ("c_kv", "k_pe"))
-            a, new_c, new_pe = cached_attention(p["attn"], a, cfg, c_kv,
-                                                k_pe, idx)
+            a, new_c, new_pe = cached_attention(p["attn"], a, cfg, cache,
+                                                layer)
         h = h + a
         with jax.named_scope("ffn"):
             a = L.rmsnorm(p["mlp_norm"], h, cfg.norm_eps)

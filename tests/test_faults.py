@@ -308,6 +308,9 @@ def test_killed_search_worker_does_not_fail_plan(fast_search, monkeypatch,
     progs = _gemm_progs(256, 256, 256)
     inline = plan_kernel_multi(progs, hw, profile=True)
 
+    # workers of a pool an earlier test left were started before the crash
+    # is armed and never see it: start from no pool
+    search_exec.shutdown_pool()
     sched = FaultSchedule([FaultSpec("worker_crash")])
     marker = sched.arm_worker_crash(directory=str(tmp_path))
     try:

@@ -205,3 +205,95 @@ def test_chunk_widths_by_cache(arch, slab, widths):
     cache = jax.eval_shape(
         lambda: build_model(cfg).init_cache(cfg, B, slab))
     assert serve.chunk_widths(cache) == widths
+
+
+# a slab that is no multiple of the block, so the last block starts early
+SLAB = 2 * mla.BLOCK + 200
+
+
+@pytest.fixture(scope="module")
+def core():
+    """(cfg, layer 0's attention weights, the jitted cached_attention over
+    a one-layer latent cache) in float32."""
+    cfg = _cfg()
+    p = jax.tree.map(lambda x: x[0],
+                     build_model(cfg).init(jax.random.PRNGKey(9))["blocks"])
+    return cfg, p["attn"], jax.jit(
+        lambda p, x, cache: mla.cached_attention(p, x, cfg, cache, 0))
+
+
+def _masked_whole_slab(p, x, cfg, c_kv, k_pe, index):
+    """The absorbed attention with one softmax over the whole slab, masked
+    at ``index``, and the chunk's own positions: what the blocked core
+    must equal."""
+    S = x.shape[1]
+    q_nope, q_pe, new_c, new_pe = mla._project(p, x, cfg,
+                                               index + jnp.arange(S))
+    q_lat = jnp.einsum("bshn,hrn->bshr", q_nope, p["wk_b"])
+    ck = jnp.concatenate([c_kv, new_c], 1)
+    kp = jnp.concatenate([k_pe, new_pe], 1)
+    s = (jnp.einsum("bshr,btr->bhst", q_lat, ck)
+         + jnp.einsum("bshp,btp->bhst", q_pe, kp)) * mla._scale(cfg)
+    seen = jnp.concatenate([
+        jnp.broadcast_to(jnp.arange(c_kv.shape[1]) < index,
+                         (S, c_kv.shape[1])),
+        jnp.tril(jnp.ones((S, S), bool))], 1)
+    w = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    o = jnp.einsum("bhst,btr->bshr", w, ck)
+    return mla._out(p, jnp.einsum("bshr,hrv->bshv", o, p["wv_b"]))
+
+
+def _slab(cfg, seed):
+    c = jax.random.normal(jax.random.PRNGKey(seed),
+                          (1, B, SLAB, cfg.kv_lora_rank))
+    pe = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                           (1, B, SLAB, cfg.qk_rope_head_dim))
+    return c, pe
+
+
+INDEXES = [0, 1, mla.BLOCK - 1, mla.BLOCK, mla.BLOCK + 1, "T-S"]
+
+
+@pytest.mark.parametrize("S", [1, 8])
+@pytest.mark.parametrize("index", INDEXES)
+def test_blocked_core_matches_masked_whole_slab(core, index, S):
+    cfg, p, attend = core
+    index = SLAB - S if index == "T-S" else index
+    c, pe = _slab(cfg, 10)
+    x = jax.random.normal(jax.random.PRNGKey(12), (B, S, cfg.d_model))
+    got, new_c, new_pe = attend(p, x, {"c_kv": c, "k_pe": pe,
+                                       "index": jnp.int32(index)})
+    with jax.default_matmul_precision("highest"):
+        want = _masked_whole_slab(p, x, cfg, c[0], pe[0], index)
+    # float32 both, the softmax summed block by block
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+    assert new_c.shape == (B, S, cfg.kv_lora_rank)
+
+
+@pytest.mark.parametrize("S", [1, 8])
+@pytest.mark.parametrize("index", INDEXES)
+def test_blocked_core_reads_no_block_past_the_filled_length(core, index, S):
+    """NaN in every position from the first block past ``index`` on, and
+    large values in the unfilled tail of the last block it reads, change
+    nothing: those blocks are never read, that tail is masked."""
+    cfg, p, attend = core
+    index = SLAB - S if index == "T-S" else index
+    width, blocks = mla.slab_blocks(index, SLAB)
+    c, pe = _slab(cfg, 20)
+    x = jax.random.normal(jax.random.PRNGKey(22), (B, S, cfg.d_model))
+    clean = attend(p, x, {"c_kv": c, "k_pe": pe, "index": jnp.int32(index)})
+    end = width * blocks
+    dirty = {k: a.at[:, :, index:end].set(1e30).at[:, :, end:].set(jnp.nan)
+             for k, a in (("c_kv", c), ("k_pe", pe))}
+    got = attend(p, x, dict(dirty, index=jnp.int32(index)))
+    assert np.isfinite(np.asarray(got[0])).all()
+    for a, b in zip(got, clean):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("index, blocks", [
+    (0, 0), (1, 1), (mla.BLOCK, 1), (mla.BLOCK + 1, 2), (SLAB, 3)])
+def test_slab_blocks(index, blocks):
+    assert mla.slab_blocks(index, SLAB) == (mla.BLOCK, blocks)
+    assert mla.slab_blocks(index, 32) == (32, -(-index // 32))

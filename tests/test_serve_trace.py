@@ -1,7 +1,8 @@
 """The serving loop under ``repro.obs`` (DESIGN_OBS.md): spans change no
-served id, count the loop's steps and reach a profiler capture, and every
+served id, count the loop's steps and reach a profiler capture, every
 device op of the compiled decode step lies in one of its named scopes
-(``repro.obs.scopes``)."""
+(``repro.obs.scopes``), and a latent cache counts the slab positions that
+latent attention's core reads."""
 import glob
 
 import jax
@@ -9,8 +10,8 @@ import numpy as np
 import pytest
 
 from repro.launch import serve
-from repro.models import build_model
-from repro.obs import scopes, trace
+from repro.models import build_model, mla
+from repro.obs import metrics, scopes, trace
 
 B, P, N, SLAB = 2, 3, 4, 16
 CHUNKS = len(serve.prompt_chunks(P, serve.PREFILL_WIDTHS))    # 2 + 1
@@ -45,6 +46,13 @@ def _generate(served):
     run = serve.greedy_generate(decode, pick, params, prompts,
                                 api.init_cache(api.cfg, B, SLAB), N)
     return np.asarray(run.generated), np.asarray(run.logits)
+
+
+def _latent_positions():
+    """``serve_latent_positions_total`` by ``part``."""
+    snap = metrics.snapshot().get("serve_latent_positions_total", {})
+    return {dict(s["labels"])["part"]: s["value"]
+            for s in snap.get("series", [])}
 
 
 def _spans(name):
@@ -105,6 +113,35 @@ def test_every_device_op_has_a_scope(served):
             "attention/core", "ffn", "lm_head"} <= found
     if served[0].cfg.n_experts:
         assert {f"ffn/{s}" for s in scopes.SCOPES["ffn"]} <= found
+
+
+def test_kv_cache_counts_no_latent_positions(served):
+    before = _latent_positions()
+    _generate(served)
+    assert _latent_positions() == before
+
+
+def test_latent_cache_counts_the_positions_its_core_reads():
+    """Moonlight reduced, over a slab of two blocks: each step reads the
+    blocks below its position count (none for the prompt's first chunk,
+    one block after), and counts the whole slab beside it."""
+    cfg = serve.serving_config("moonlight-16b-a3b", reduced=True)
+    api = build_model(cfg)
+    params = serve.init_params(api, 0)
+    slab = 2 * mla.BLOCK
+    decode, pick = serve.compile_greedy(
+        jax.jit(api.decode_step, donate_argnums=(2,)), params,
+        np.zeros((B, 1), np.int32), api.init_cache(cfg, B, slab),
+        cfg.vocab_size)
+    before = _latent_positions()
+    serve.greedy_generate(decode, pick, params,
+                          np.ones((B, P), np.int32),
+                          api.init_cache(cfg, B, slab), N)
+    after = _latent_positions()
+    read, whole = (after[k] - before.get(k, 0) for k in ("read", "slab"))
+    steps = CHUNKS + N - 1
+    assert whole == steps * slab
+    assert read == (steps - 1) * mla.BLOCK < whole
 
 
 def test_scope_of_op_names():

@@ -169,7 +169,9 @@ def test_latent_serve_step_fits_and_keeps_scopes(one_chip, width):
     8 of 64 experts held, batch 16, slab 8192) compiles for one chip and
     fits its HBM.  Decoding, it reads the latent slab where it lies: no
     temporary holds a layer's slab (16 x 8192 x 512 bfloat16, 134 MB), and
-    every device op lies in a named scope."""
+    every device op lies in a named scope, the core's loop over the slab's
+    blocks in ``attention/core``.  A prompt chunk's core builds no scores
+    over the whole slab (16 x 16 heads x 128 x 8192 float32, 1.07 GB)."""
     import dataclasses
     from repro.launch.serve import serving_config
     from repro.models import build_model
@@ -191,6 +193,7 @@ def test_latent_serve_step_fits_and_keeps_scopes(one_chip, width):
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < V5E_USABLE_HBM)
     assert mem.alias_size_in_bytes > 4e9             # the cache, in place
+    assert mem.temp_size_in_bytes < 16 * 16 * width * 8192 * 4
     if width == 1:
         assert mem.temp_size_in_bytes < 16 * 8192 * 512 * 2
         text = compiled.as_text()
@@ -199,10 +202,14 @@ def test_latent_serve_step_fits_and_keeps_scopes(one_chip, width):
         assert {"layer_loop", "attention/proj", "attention/kv_cache",
                 "attention/core", "ffn/router", "ffn/experts",
                 "lm_head"} <= set(scope.values())
+        instrs = scopes.instructions(text)
+        loop = [n for n, (op, name, _) in instrs.items()
+                if op not in scopes.NO_WORK and name
+                and "/attention/core/while/" in name]
+        assert loop and {scope[n] for n in loop} == {"attention/core"}
         # the trailing prefetch of the next execution's first weight (a
         # copy-done nothing uses) takes the scope of that weight's other
         # user, the dense MLP's ("ffn"), and adds nothing to the core's
-        instrs = scopes.instructions(text)
         users = {}
         for n, (_, _, operands) in instrs.items():
             for o in operands:
